@@ -40,6 +40,7 @@ import time
 from pathlib import Path
 from typing import Dict
 
+from repro.configs import backend
 from repro.obs import metrics as MT
 from repro.obs import xprof
 
@@ -137,6 +138,7 @@ def main() -> None:
                     help="warn on benches >15%% slower than this "
                          "name,us_per_call CSV")
     args = ap.parse_args()
+    backend.enable_compile_cache()
 
     csv = []
     current: Dict[str, Dict[str, float]] = {}

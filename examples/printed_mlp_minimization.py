@@ -22,6 +22,7 @@ import argparse
 import sys
 import time
 
+from repro.configs import backend
 from repro.configs.printed_mlp import PRINTED_MLPS
 from repro.core import batch_eval as BE
 from repro.core import minimize as MZ
@@ -39,6 +40,7 @@ def main(argv=None):
                     help="persistent evaluation cache dir (stable default "
                          "so a re-run retrains nothing)")
     args = ap.parse_args(argv)
+    backend.enable_compile_cache()
 
     cfg = PRINTED_MLPS[args.dataset]
     n_layers = len(cfg.layer_dims) - 1
@@ -124,6 +126,7 @@ def main(argv=None):
     print(f"  area {sc.area_mm2/100:.2f} -> {asc.area_mm2/100:.2f} cm2 "
           f"({rep.area_gain:.2f}x on top of minimization), "
           f"accuracy {acc_exact:.3f} -> {acc_approx:.3f}")
+    res.update(chosen=chosen, chosen_netlist_accuracy=acc_exact)
     return res
 
 

@@ -8,9 +8,9 @@ function, ``vmap``-ed over the input batch. Every intermediate is an exact
 machine integer — int32 when the verifier's per-node width bounds say every
 datapath word fits a 32-bit lane (`repro.verify.netlist.fits_int32`; the
 bound is inclusive at width 32, i.e. exactly the int32 range), int64 (under
-a local ``enable_x64`` scope) otherwise — so the simulation reproduces
-`minimize.integer_forward` bit-for-bit; there is no float anywhere in the
-datapath.
+a local ``jax.enable_x64(True)`` scope) otherwise — so the simulation
+reproduces `minimize.integer_forward` bit-for-bit; there is no float
+anywhere in the datapath.
 
 For *population* throughput (the GA's netlist-exact objective) use
 `repro.kernels.netlist_sim`: this module rebuilds a jitted executable per
@@ -27,7 +27,6 @@ from typing import Dict, List, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro.circuit import ir
 
@@ -155,7 +154,7 @@ class Simulator:
             self._fn = jax.jit(batch)
 
     def _scope(self):
-        return enable_x64() if self._x64 else contextlib.nullcontext()
+        return jax.enable_x64(True) if self._x64 else contextlib.nullcontext()
 
     def run(self, x_int: np.ndarray) -> Dict[str, np.ndarray]:
         x = np.asarray(x_int)

@@ -10,24 +10,29 @@ JAX computation, or drive them through the ``REPRO_*`` environment
 variables it reads.
 
 ``default_netlist_engine`` is the routing policy for
-`repro.kernels.netlist_sim`: the Pallas kernel where Pallas compiles to
-real hardware (TPU), the wave-scheduled ``lax.scan`` engine everywhere
-else (on CPU the Pallas path only exists in interpret mode, which is a
-correctness oracle, not a fast path). ``REPRO_NETLIST_ENGINE`` overrides.
+`repro.kernels.netlist_sim`: the wave-scheduled ``lax.scan`` engine on
+every backend. The Pallas netlist kernel does not compile for TPU yet (its
+``(1, N)`` table blocks are not (8, 128)-aligned), so it runs only when
+asked for, in interpret mode off-TPU. ``REPRO_NETLIST_ENGINE`` overrides.
+
+``enable_compile_cache`` places JAX's persistent compilation cache for the
+entry points (examples, benchmarks, ``chip_smoke.py``); importing ``repro``
+never turns it on.
 """
 from __future__ import annotations
 
 import os
 import warnings
 from multiprocessing import cpu_count
+from pathlib import Path
 
 import jax
 
 
 def jax_enable_x64(use_x64: bool) -> None:
     """Default integer/float width 64 bits process-wide. The netlist-sim
-    engines prefer the *local* ``jax.experimental.enable_x64`` scope and
-    only need this for debugging sessions."""
+    engines prefer the *local* ``jax.enable_x64(True)`` scope and only need
+    this for debugging sessions."""
     if not use_x64:
         use_x64 = bool(os.getenv("JAX_ENABLE_X64", 0))
     jax.config.update("jax_enable_x64", use_x64)
@@ -69,12 +74,28 @@ def set_debug_nan(flag: bool) -> None:
 
 
 def default_netlist_engine() -> str:
-    """'pallas' on real TPU hardware, 'levels' elsewhere; overridable with
+    """'levels' on every backend; overridable with
     ``REPRO_NETLIST_ENGINE=levels|pallas|ref``."""
     env = os.environ.get("REPRO_NETLIST_ENGINE", "").strip().lower()
     if env in ("levels", "pallas", "ref"):
         return env
-    return "pallas" if jax.default_backend() == "tpu" else "levels"
+    return "levels"
+
+
+# <repo root>/.jax_cache: a fixed path, because the path is part of the
+# cache's key and a directory that moves between runs never hits
+REPO_COMPILE_CACHE = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> None:
+    """Turn on JAX's persistent compilation cache for an entry point.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    this sets nothing. Otherwise the cache goes to
+    ``<repo root>/.jax_cache``."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          str(REPO_COMPILE_CACHE))
 
 
 def configure(*, platform: str | None = None, x64: bool | None = None,

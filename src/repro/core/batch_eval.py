@@ -483,6 +483,10 @@ def _compile_and_price(params_pop, specs, masks_serial, xte, yte, *,
     ``quarantine`` — the rest of the population prices and returns
     normally. Retrying matters for transient faults (torn files, flaky
     workers); deterministic failures burn both attempts and quarantine.
+    ``ImportError`` and ``jax.errors.JaxRuntimeError`` are the process's or
+    the device's faults, not the candidate's, and propagate; so does any
+    error of the packed-population launch, which every exact candidate
+    shares.
     """
     from repro import approx as AX               # lazy: approx imports us
     from repro import circuit as CIRC            # lazy: circuit imports us
@@ -495,7 +499,7 @@ def _compile_and_price(params_pop, specs, masks_serial, xte, yte, *,
     delays: Dict[int, int] = {}
 
     for p, spec in enumerate(specs):
-        err: Optional[BaseException] = None
+        err: Optional[Exception] = None
         stage = "compile"
         for attempt in (1, 2):
             try:
@@ -532,9 +536,9 @@ def _compile_and_price(params_pop, specs, masks_serial, xte, yte, *,
                     delays[p] = net.critical_path_levels()
                 err = None
                 break
-            except (KeyboardInterrupt, SystemExit):
+            except (ImportError, jax.errors.JaxRuntimeError):
                 raise
-            except BaseException as e:
+            except Exception as e:
                 err = e
         if err is not None:
             rec = QuarantineRecord(spec.to_json(), stage,
@@ -550,53 +554,18 @@ def _compile_and_price(params_pop, specs, masks_serial, xte, yte, *,
             full[p] = _worst_case_result(spec)
 
     # one packed-population launch scores every deferred exact candidate;
-    # if the batch itself faults, fall back to per-candidate serial
-    # simulation under the same retry-once-then-quarantine contract so one
-    # poisoned netlist cannot take the generation's scores down with it
+    # a fault here is the engine's, so it propagates
     if nets:
         todo_p = sorted(nets)
-        try:
-            packs = [_packed_netlist_for(
-                pack_key(specs[p]) if pack_key else None, nets[p], NS)
-                for p in todo_p]
-            xq = np.stack([np.asarray(
-                MZ.quantize_inputs(compiled[p], xte), np.int64)
-                for p in todo_p])
-            pop_acc = NS.population_accuracy(NS.pack_population(packs),
-                                             xq, yte)
-            for j, p in enumerate(todo_p):
-                accs[p] = float(pop_acc[j])
-        except (KeyboardInterrupt, SystemExit):
-            raise
-        except BaseException:
-            for p in todo_p:
-                err2: Optional[BaseException] = None
-                for _attempt in (1, 2):
-                    try:
-                        accs[p] = float(CIRC.netlist_accuracy(
-                            nets[p], compiled[p], xte, yte))
-                        err2 = None
-                        break
-                    except (KeyboardInterrupt, SystemExit):
-                        raise
-                    except BaseException as e:
-                        err2 = e
-                if err2 is not None:
-                    rec = QuarantineRecord(specs[p].to_json(), "score",
-                                           type(err2).__name__, str(err2),
-                                           attempts=2)
-                    MT.counter("eval.quarantine.score").inc()
-                    TR.event("eval.quarantine", stage="score",
-                             error=rec.error, message=rec.message,
-                             spec=rec.spec_json)
-                    if quarantine is not None:
-                        quarantine.append(rec)
-                    else:
-                        warnings.warn(
-                            f"spec quarantined (score: {rec.error}: "
-                            f"{rec.message}); worst-case fitness assigned")
-                    full[p] = _worst_case_result(specs[p])
-                    del compiled[p]
+        packs = [_packed_netlist_for(
+            pack_key(specs[p]) if pack_key else None, nets[p], NS)
+            for p in todo_p]
+        xq = np.stack([np.asarray(
+            MZ.quantize_inputs(compiled[p], xte), np.int64)
+            for p in todo_p])
+        pop_acc = NS.population_accuracy(NS.pack_population(packs), xq, yte)
+        for j, p in enumerate(todo_p):
+            accs[p] = float(pop_acc[j])
 
     # stack per-layer integer weights / codebooks and price the whole
     # population in one hw_model call (pad codebooks to the layer's max k).
@@ -666,7 +635,8 @@ def evaluate_population(cfg: PrintedMLPConfig, specs: Sequence[ModelMin], *,
     A candidate whose compile/score fails is retried once, then quarantined
     with worst-case fitness (never cached, so a fixed toolchain re-evaluates
     it) and a :class:`QuarantineRecord` appended to ``quarantine`` — one
-    poisoned genome cannot abort the generation.
+    poisoned genome cannot abort the generation. Faults of the process, the
+    device or the shared packed-population launch raise out of this call.
     """
     specs = list(specs)
     from repro.verify.diagnostics import verify_enabled

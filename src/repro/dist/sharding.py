@@ -60,16 +60,6 @@ def _axis_sizes(mesh) -> Dict[str, int]:
     return dict(mesh.shape)
 
 
-def abstract_mesh(axis_sizes: Sequence[int], axis_names: Sequence[str]):
-    """Version-portable AbstractMesh: jax >= 0.5 takes (sizes, names),
-    0.4.x takes ((name, size), ...)."""
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
-
-
 def batch_axes(mesh) -> Tuple[str, ...]:
     """All non-tensor-parallel mesh axes, in mesh order — the axes a global
     batch is sharded over (a "pod" super-axis composes with "data")."""

@@ -23,9 +23,3 @@ Each kernel ships kernel.py (pl.pallas_call + BlockSpec), ops.py (jit'd
 wrapper; interpret=True on CPU) and ref.py (oracle); tests sweep
 shapes/dtypes and assert bit-exactness / allclose against the oracle.
 """
-from jax.experimental.pallas import tpu as _pltpu
-
-# jax renamed TPUCompilerParams -> CompilerParams; kernels import this alias
-# so they build on both sides of the rename.
-CompilerParams = getattr(_pltpu, "CompilerParams", None) or \
-    getattr(_pltpu, "TPUCompilerParams")

@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import CompilerParams as _CompilerParams
-
 
 def _cmm_kernel(x_ref, idx_ref, cb_ref, o_ref, acc_ref, *, k_steps: int,
                 n_clusters: int):
@@ -66,7 +64,7 @@ def clustered_matmul_pallas(x, idx, codebook, *, block_m: int = 128,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, idx.astype(jnp.int32), codebook)

@@ -21,9 +21,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.circuit import ir
-from repro.kernels import CompilerParams as _CompilerParams
 
 _SHL = int(ir.Op.SHL)
 _ADD = int(ir.Op.ADD)
@@ -104,7 +104,7 @@ def netlist_sim_pallas(op, arg_a, arg_b, shift, val, level_ptr, input_pos,
         ],
         out_specs=pl.BlockSpec((1, block_b, C), lambda p, t: (p, t, 0)),
         out_shape=jax.ShapeDtypeStruct((P, B, C), jnp.int32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(op, arg_a, arg_b, shift, val, level_ptr, input_pos, argmax_pos, x)

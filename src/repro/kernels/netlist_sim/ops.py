@@ -10,23 +10,24 @@ executables instead of retracing.
 
 Two engines, one packing, one oracle:
 
-* ``"levels"`` (default off-TPU) — a host-built global wave schedule over
-  the concatenated node tables, executed as ONE ``lax.scan`` over fixed
-  (window,)-wide waves with branchless opcode dispatch. Each global
-  topological level is chunked into ceil(count/window) waves; every wave of
-  level l-1 precedes every wave of level l, so intra-wave independence is
-  inherited from the level structure. Padding lanes carry ``op = NOP`` and
-  scatter to a dummy slot.
-* ``"pallas"`` (default on TPU, int32-width populations) — the bespoke
-  kernel in `kernel.py`: grid over candidates x input tiles, levels
-  unrolled inside the kernel. Runs interpret=True off-TPU like the other
-  five kernels.
+* ``"levels"`` (the default on every backend) — a host-built global wave
+  schedule over the concatenated node tables, executed as ONE ``lax.scan``
+  over fixed (window,)-wide waves with branchless opcode dispatch. Each
+  global topological level is chunked into ceil(count/window) waves; every
+  wave of level l-1 precedes every wave of level l, so intra-wave
+  independence is inherited from the level structure. Padding lanes carry
+  ``op = NOP`` and scatter to a dummy slot.
+* ``"pallas"`` (explicit ``engine="pallas"`` only, int32-width
+  populations) — the bespoke kernel in `kernel.py`: grid over candidates x
+  input tiles, levels unrolled inside the kernel. Runs interpret=True
+  off-TPU like the other five kernels. The TPU compiler refuses it: its
+  ``(1, N)`` table blocks are not (8, 128)-aligned.
 
 Lane width is the verifier's per-node bound maximized over the population:
-int32 when every word fits 32 bits, else int64 under a local ``enable_x64``
-scope (`repro.verify.netlist.fits_int32` semantics). Both engines are
-bit-exact against `circuit.simulate.simulate` and the NumPy oracle in
-`ref.py` — tested on all four datasets.
+int32 when every word fits 32 bits, else int64 under a local
+``jax.enable_x64(True)`` scope (`repro.verify.netlist.fits_int32`
+semantics). Both engines are bit-exact against `circuit.simulate.simulate`
+and the NumPy oracle in `ref.py` — tested on all four datasets.
 """
 from __future__ import annotations
 
@@ -38,7 +39,6 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from repro.circuit import ir
 from repro.kernels.netlist_sim.kernel import netlist_sim_pallas
@@ -204,7 +204,7 @@ def _run_engine(pop: PackedPopulation, x: np.ndarray, engine: str,
     (NOP wave lanes, repeated candidates, repeated batch rows)."""
     P, B = x.shape[0], x.shape[1]
     fits32 = pop.max_width <= 32
-    scope = contextlib.nullcontext() if fits32 else enable_x64()
+    scope = contextlib.nullcontext() if fits32 else jax.enable_x64(True)
     dtype = jnp.int32 if fits32 else jnp.int64
     lane = "int32" if fits32 else "int64"
 
@@ -244,7 +244,8 @@ def _run_engine(pop: PackedPopulation, x: np.ndarray, engine: str,
             pad = bt - tile.shape[0]
             if pad:
                 tile = np.concatenate([tile, tile[-1:].repeat(pad, 0)])
-            with (contextlib.nullcontext() if fits32 else enable_x64()):
+            with (contextlib.nullcontext() if fits32
+                  else jax.enable_x64(True)):
                 return _run_levels.lower(*args, vals0, inp_cols, am_cols,
                                          jnp.asarray(tile.astype(dtype)))
 

@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import CompilerParams as _CompilerParams
-
 
 def _qmm_kernel(x_ref, w_ref, s_ref, o_ref, acc_ref, *, k_steps: int):
     @pl.when(pl.program_id(2) == 0)
@@ -60,7 +58,7 @@ def quant_matmul_pallas(x, w_q, scales, *, block_m: int = 128,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, w_q, scales.reshape(1, N))

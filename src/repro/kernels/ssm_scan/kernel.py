@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import CompilerParams as _CompilerParams
-
 
 def _ssm_kernel(u_ref, dt_ref, b_ref, c_ref, a_ref, d_ref, y_ref, h_ref, *,
                 bt: int):
@@ -72,7 +70,7 @@ def ssm_scan_pallas(u, dt, B_, C_, A, D, *, block_d: int = 512,
                                lambda b, i, t: (b, t, i)),
         out_shape=jax.ShapeDtypeStruct((Bsz, T, d), u.dtype),
         scratch_shapes=[pltpu.VMEM((block_d, N), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(u, dt, B_, C_, A.astype(jnp.float32),

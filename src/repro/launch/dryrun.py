@@ -114,10 +114,9 @@ def lower_cell(cfg: ArchConfig, shape: ShapeConfig, mesh, *, unroll: bool,
     in_specs = SP.input_specs(cfg, shape)
     in_shard = SP.input_shardings(cfg, shape, mesh)
 
-    # jax.set_mesh is post-0.4.x; `with mesh:` is its 0.4 equivalent (all
-    # shardings below are explicit NamedShardings, the context only scopes
-    # spec resolution)
-    with (jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh):
+    # all shardings below are explicit NamedShardings; the mesh context
+    # only scopes spec resolution
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             state_shapes = SP.abstract_train_state(cfg)
             state_shard = SP.train_state_shardings(cfg, mesh, state_shapes)

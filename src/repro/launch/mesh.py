@@ -10,13 +10,10 @@ import jax
 
 
 def _mesh(shape, axes):
-    """jax.make_mesh across versions: 0.4.x has no axis_types kwarg; newer
-    versions default to Auto axes, which is what every caller here wants."""
-    try:
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, axes)
+    """`jax.make_mesh` with Auto axes, which is what every caller here
+    wants."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

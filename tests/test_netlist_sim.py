@@ -1,8 +1,9 @@
 """Population-batched netlist simulation (`repro.kernels.netlist_sim`):
 packing round-trips, padded mixed-size populations, bit-exactness of every
 engine against `circuit.simulate`, lane-width selection off the verifier's
-per-node bounds, and the batched/serial/fallback wiring in
+per-node bounds, and the batched/serial/fault wiring in
 `core.batch_eval`."""
+import jax
 import numpy as np
 import pytest
 
@@ -218,12 +219,14 @@ def test_wide_population_takes_int64_lanes():
 def test_engine_env_override(monkeypatch):
     monkeypatch.setenv("REPRO_NETLIST_ENGINE", "ref")
     assert backend.default_netlist_engine() == "ref"
+    monkeypatch.setenv("REPRO_NETLIST_ENGINE", "pallas")
+    assert backend.default_netlist_engine() == "pallas"
     monkeypatch.delenv("REPRO_NETLIST_ENGINE")
-    assert backend.default_netlist_engine() in ("levels", "pallas")
+    assert backend.default_netlist_engine() == "levels"
 
 
 # ---------------------------------------------------------------------------
-# batch_eval wiring: default objective, cache keys, fault fallback
+# batch_eval wiring: default objective, cache keys, fault propagation
 # ---------------------------------------------------------------------------
 
 
@@ -251,42 +254,52 @@ def test_mixed_input_bits_population_matches_serial():
         assert r.accuracy == MZ.evaluate_spec(cfg, s, epochs=8).accuracy
 
 
-def test_batched_sim_fault_falls_back_to_serial(monkeypatch):
-    """A fault in the batched launch degrades to per-candidate serial
-    netlist scoring with identical results — one bad batch must not
-    quarantine a healthy generation."""
+def test_packed_launch_fault_propagates(monkeypatch, tmp_path):
+    """A fault in the packed-population launch is the engine's, shared by
+    every exact candidate: it raises out of `evaluate_population` instead
+    of quarantining the generation, and nothing is cached."""
     cfg = PRINTED_MLPS["seeds"]
     n = len(cfg.layer_dims) - 1
     specs = [ModelMin.uniform(n, bits=8),
              ModelMin.uniform(n, bits=3, sparsity=0.3)]
-    expected = BE.evaluate_population(cfg, specs, epochs=8)
 
     def boom(*a, **k):
         raise RuntimeError("injected batched-sim fault")
 
-    monkeypatch.setattr(BE, "_packed_netlist_for", boom)
-    got = BE.evaluate_population(cfg, specs, epochs=8)
-    assert [r.accuracy for r in got] == [r.accuracy for r in expected]
-    assert [r.area_mm2 for r in got] == [r.area_mm2 for r in expected]
+    monkeypatch.setattr(NS, "population_accuracy", boom)
+    cache = BE.EvalCache(tmp_path / "evals.json")
+    recs = []
+    with pytest.raises(RuntimeError, match="injected batched-sim fault"):
+        BE.evaluate_population(cfg, specs, epochs=8, cache=cache,
+                               quarantine=recs)
+    assert recs == [] and len(cache) == 0
 
 
-def test_batched_and_serial_sim_fault_quarantines(monkeypatch):
-    """When the serial fallback fails too, candidates quarantine with
-    worst-case fitness at stage 'score' and are never cached."""
+@pytest.mark.parametrize("make_error", [
+    lambda: ImportError("injected: missing module"),
+    lambda: jax.errors.JaxRuntimeError("injected: device lost"),
+], ids=["import", "jax_runtime"])
+def test_process_fault_in_candidate_propagates(make_error):
+    """An ImportError or a JaxRuntimeError raised inside a candidate's
+    evaluation is the process's or the device's fault: it propagates
+    instead of burning the retry and quarantining the candidate."""
     cfg = PRINTED_MLPS["seeds"]
     n = len(cfg.layer_dims) - 1
     specs = [ModelMin.uniform(n, bits=8)]
+    calls = []
 
-    def boom(*a, **k):
-        raise RuntimeError("injected sim fault")
+    def hook(spec, attempt):
+        calls.append(attempt)
+        raise make_error()
 
-    monkeypatch.setattr(BE, "_packed_netlist_for", boom)
-    monkeypatch.setattr(circuit, "netlist_accuracy", boom)
+    prev = BE.set_eval_fault_hook(hook)
     recs = []
-    rs = BE.evaluate_population(cfg, specs, epochs=8, quarantine=recs)
-    assert len(recs) == 1 and recs[0].stage == "score"
-    assert rs[0].accuracy == 0.0
-    assert rs[0].area_mm2 == BE.QUARANTINE_AREA_MM2
+    try:
+        with pytest.raises(type(make_error())):
+            BE.evaluate_population(cfg, specs, epochs=8, quarantine=recs)
+    finally:
+        BE.set_eval_fault_hook(prev)
+    assert calls == [1] and recs == []
 
 
 def test_pack_cache_reuses_tables(monkeypatch):

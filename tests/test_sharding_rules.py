@@ -5,14 +5,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AbstractMesh
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import ARCHS
 from repro.dist import sharding as SH
 from repro.nn import transformer as T
 
-MESH = SH.abstract_mesh((16, 16), ("data", "model"))
-MESH3 = SH.abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _abstract(name):
