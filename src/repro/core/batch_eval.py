@@ -557,20 +557,29 @@ def _compile_and_price(params_pop, specs, masks_serial, xte, yte, *,
     # a fault here is the engine's, so it propagates
     if nets:
         todo_p = sorted(nets)
-        packs = [_packed_netlist_for(
-            pack_key(specs[p]) if pack_key else None, nets[p], NS)
-            for p in todo_p]
-        xq = np.stack([np.asarray(
-            MZ.quantize_inputs(compiled[p], xte), np.int64)
-            for p in todo_p])
-        pop_acc = NS.population_accuracy(NS.pack_population(packs), xq, yte)
+        with TR.span("netlist_sim.pack", n=len(todo_p)):
+            packs = [_packed_netlist_for(
+                pack_key(specs[p]) if pack_key else None, nets[p], NS)
+                for p in todo_p]
+            xq = np.stack([np.asarray(
+                MZ.quantize_inputs(compiled[p], xte), np.int64)
+                for p in todo_p])
+            pop = NS.pack_population(packs)
+        pop_acc = NS.population_accuracy(pop, xq, yte)
         for j, p in enumerate(todo_p):
             accs[p] = float(pop_acc[j])
 
-    # stack per-layer integer weights / codebooks and price the whole
-    # population in one hw_model call (pad codebooks to the layer's max k).
-    # Only cleanly-compiled exact candidates take part; approx-scored and
-    # quarantined ones already carry their full EvalResult.
+    with TR.span("eval.price", n=len(compiled)):
+        return _price(specs, compiled, full, accs, delays)
+
+
+def _price(specs, compiled: Dict[int, MZ.CompiledMLP],
+           full: Dict[int, MZ.EvalResult], accs: Dict[int, float],
+           delays: Dict[int, int]) -> List[MZ.EvalResult]:
+    """Stack per-layer integer weights / codebooks and price the whole
+    population in one hw_model call (codebooks padded to the layer's max
+    k). Only cleanly-compiled exact candidates take part; approx-scored
+    and quarantined ones already carry their full EvalResult."""
     ok = sorted(compiled)
     cost = None
     if ok:
@@ -639,60 +648,78 @@ def evaluate_population(cfg: PrintedMLPConfig, specs: Sequence[ModelMin], *,
     device or the shared packed-population launch raise out of this call.
     """
     specs = list(specs)
-    from repro.verify.diagnostics import verify_enabled
-    if specs and verify_enabled():
-        # static spec lint before any costly QAT: gene-range/arch
-        # legality + serialize->parse->serialize byte-stability (a
-        # non-round-tripping spec would fracture the cache keyspace)
-        from repro.verify.spec import check_specs
-        check_specs(specs, cfg)
-    results: Dict[str, MZ.EvalResult] = {}
-    todo: List[ModelMin] = []
-    queued = set()
-    n_hits = 0
-    for s in specs:
-        k = s.to_json()
-        if k in results or k in queued:
-            continue
-        hit = (cache.get(cfg.name, seed, epochs, s,
-                         netlist=netlist or s.has_approx)
-               if cache else None)
-        n_hits += hit is not None
-        if hit is not None and hit.delay_levels is not None:
-            # entries from caches predating the circuit compiler carry no
-            # delay — fall through and re-evaluate so they upgrade in place
-            results[k] = hit
-        else:
-            todo.append(s)
-            queued.add(k)
+    with TR.span("eval.population", dataset=cfg.name, n=len(specs)):
+        results = _evaluate_unique(cfg, specs, epochs=epochs, seed=seed,
+                                   cache=cache, netlist=netlist,
+                                   quarantine=quarantine)
+    return [results[s.to_json()] for s in specs]
 
-    MT.counter("eval.specs_requested").inc(len(specs))
-    MT.counter("eval.specs_cached").inc(n_hits)
-    MT.counter("eval.specs_evaluated").inc(len(todo))
-    TR.event("eval.batch", dataset=cfg.name, requested=len(specs),
-             hits=n_hits, evaluated=len(todo))
+
+def _evaluate_unique(cfg: PrintedMLPConfig, specs: List[ModelMin], *,
+                     epochs: int, seed: int, cache: Optional[EvalCache],
+                     netlist: bool,
+                     quarantine: Optional[List[QuarantineRecord]]
+                     ) -> Dict[str, MZ.EvalResult]:
+    """`evaluate_population`'s body: spec json -> result, each distinct
+    spec once. One span per stage: ``eval.prepare`` (lookup, staging,
+    uploads), ``eval.finetune``, ``eval.fetch``, ``eval.compile_price``."""
+    with TR.span("eval.prepare"):
+        from repro.verify.diagnostics import verify_enabled
+        if specs and verify_enabled():
+            # static spec lint before any costly QAT: gene-range/arch
+            # legality + serialize->parse->serialize byte-stability (a
+            # non-round-tripping spec would fracture the cache keyspace)
+            from repro.verify.spec import check_specs
+            check_specs(specs, cfg)
+        results: Dict[str, MZ.EvalResult] = {}
+        todo: List[ModelMin] = []
+        queued = set()
+        n_hits = 0
+        for s in specs:
+            k = s.to_json()
+            if k in results or k in queued:
+                continue
+            hit = (cache.get(cfg.name, seed, epochs, s,
+                             netlist=netlist or s.has_approx)
+                   if cache else None)
+            n_hits += hit is not None
+            if hit is not None and hit.delay_levels is not None:
+                # entries from caches predating the circuit compiler carry
+                # no delay — fall through and re-evaluate so they upgrade
+                # in place
+                results[k] = hit
+            else:
+                todo.append(s)
+                queued.add(k)
+
+        MT.counter("eval.specs_requested").inc(len(specs))
+        MT.counter("eval.specs_cached").inc(n_hits)
+        MT.counter("eval.specs_evaluated").inc(len(todo))
+        TR.event("eval.batch", dataset=cfg.name, requested=len(specs),
+                 hits=n_hits, evaluated=len(todo))
+        if todo:
+            n_real = len(todo)
+            # pad to a power-of-two bucket by repeating the last spec: the
+            # jit specializes on the population axis, and a GA's uncached
+            # count differs almost every generation — bucketing keeps one
+            # compiled executable per bucket instead of a retrace per
+            # generation
+            bucket = 1 << (n_real - 1).bit_length()
+            padded = todo + [todo[-1]] * (bucket - n_real)
+            params0, (xtr, ytr, xte, yte) = MZ.pretrain(cfg, seed=seed)
+            bits, ks = stack_specs(padded)
+            stacked, masks_serial = stack_masks(params0, padded)
+            masks = tuple(jnp.asarray(m) for m in stacked)
+            # population-bucket padding accounting (same convention as
+            # netlist_sim's lane padding): real specs vs padded bucket slots
+            MT.counter("eval.pad.specs_real").inc(n_real)
+            MT.counter("eval.pad.specs_total").inc(bucket)
+            bits_j, ks_j = jnp.asarray(bits), jnp.asarray(ks)
+            xtr_j, ytr_j = jnp.asarray(xtr), jnp.asarray(ytr)
+            args = (params0, bits_j, ks_j, masks, xtr_j, ytr_j)
+            kw = dict(epochs=epochs, lr=2e-3)
 
     if todo:
-        n_real = len(todo)
-        # pad to a power-of-two bucket by repeating the last spec: the jit
-        # specializes on the population axis, and a GA's uncached count
-        # differs almost every generation — bucketing keeps one compiled
-        # executable per bucket instead of a retrace per generation
-        bucket = 1 << (n_real - 1).bit_length()
-        padded = todo + [todo[-1]] * (bucket - n_real)
-        params0, (xtr, ytr, xte, yte) = MZ.pretrain(cfg, seed=seed)
-        bits, ks = stack_specs(padded)
-        stacked, masks_serial = stack_masks(params0, padded)
-        masks = tuple(jnp.asarray(m) for m in stacked)
-        # population-bucket padding accounting (same convention as
-        # netlist_sim's lane padding): real specs vs padded bucket slots
-        MT.counter("eval.pad.specs_real").inc(n_real)
-        MT.counter("eval.pad.specs_total").inc(bucket)
-        MT.histogram("eval.bucket_util_hist").observe(n_real / bucket)
-        bits_j, ks_j = jnp.asarray(bits), jnp.asarray(ks)
-        xtr_j, ytr_j = jnp.asarray(xtr), jnp.asarray(ytr)
-        args = (params0, bits_j, ks_j, masks, xtr_j, ytr_j)
-        kw = dict(epochs=epochs, lr=2e-3)
         # the dispatch wrapper times DISPATCH of the population jit (never
         # runs inside traced code); the first call per (dataset, bucket,
         # epochs) pays XLA compilation, is tagged `first` so reports split
@@ -711,8 +738,9 @@ def evaluate_population(cfg: PrintedMLPConfig, specs: Sequence[ModelMin], *,
                              dataset=cfg.name, bucket=bucket, n=n_real):
                 trained = _population_finetune(*args, **kw)
                 jax.block_until_ready(trained)
-        trained = jax.tree_util.tree_map(
-            lambda a: np.asarray(a[:n_real]), trained)
+        with TR.span("eval.fetch", n=n_real):
+            trained = jax.tree_util.tree_map(
+                lambda a: np.asarray(a[:n_real]), trained)
         recs: List[QuarantineRecord] = []
 
         def pack_key(s: ModelMin) -> str:
@@ -746,8 +774,7 @@ def evaluate_population(cfg: PrintedMLPConfig, specs: Sequence[ModelMin], *,
     # generation is not a multi-MB rewrite)
     if cache is not None and (todo or n_hits):
         cache.flush()
-
-    return [results[s.to_json()] for s in specs]
+    return results
 
 
 def make_batch_evaluator(cfg: PrintedMLPConfig, *, epochs: int = 150,

@@ -29,6 +29,7 @@ from repro.core import quantization as Q
 from repro.core.compression_spec import LayerMin, ModelMin
 from repro.data.uci import dataset_for
 from repro.nn import mlp as M
+from repro.obs import trace as TR
 
 
 # ---------------------------------------------------------------------------
@@ -131,6 +132,11 @@ class CompiledMLP:
 
 
 def compile_bespoke(params, spec: ModelMin, masks) -> CompiledMLP:
+    with TR.span("minimize.compile_bespoke"):
+        return _compile_bespoke(params, spec, masks)
+
+
+def _compile_bespoke(params, spec: ModelMin, masks) -> CompiledMLP:
     q_layers, scales, biases, clusters, w_bits = [], [], [], [], []
     for i, layer in enumerate(params["layers"]):
         lm = spec.layers[i]

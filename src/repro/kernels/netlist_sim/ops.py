@@ -356,22 +356,15 @@ def simulate_population(pop: PackedPopulation, x: np.ndarray, *,
 def _account_padding(stats: Dict) -> None:
     """Always-on packing-efficiency accounting for one launch. Counters
     hold exact lane/row totals (deterministic functions of the evaluated
-    populations, so they keep the checkpoint bit-identity contract);
-    utilization ratios go to gauges/histograms; the full per-launch stats
-    ride the trace as a ``netlist_sim.padding`` event when tracing."""
-    lanes_u, lanes_t = stats["lanes_used"], stats["lanes_total"]
-    rows_r, rows_t = stats["rows_real"], stats["rows_total"]
-    MT.counter("netlist_sim.pad.lanes_used").inc(lanes_u)
-    MT.counter("netlist_sim.pad.lanes_total").inc(lanes_t)
-    MT.counter("netlist_sim.pad.rows_real").inc(rows_r)
-    MT.counter("netlist_sim.pad.rows_total").inc(rows_t)
+    populations, so they keep the checkpoint bit-identity contract); the
+    full per-launch stats ride the trace as a ``netlist_sim.padding``
+    event when tracing."""
+    MT.counter("netlist_sim.pad.lanes_used").inc(stats["lanes_used"])
+    MT.counter("netlist_sim.pad.lanes_total").inc(stats["lanes_total"])
+    MT.counter("netlist_sim.pad.rows_real").inc(stats["rows_real"])
+    MT.counter("netlist_sim.pad.rows_total").inc(stats["rows_total"])
     MT.counter("netlist_sim.pad.cand_real").inc(stats["cand_real"])
     MT.counter("netlist_sim.pad.cand_total").inc(stats["cand_total"])
-    lane_util = lanes_u / max(lanes_t, 1)
-    MT.gauge("netlist_sim.lane_util").set(lane_util)
-    MT.histogram("netlist_sim.lane_util_hist").observe(lane_util)
-    MT.histogram("netlist_sim.row_util_hist").observe(
-        rows_r / max(rows_t, 1))
     if TR.active():
         TR.event("netlist_sim.padding",
                  **{k: (PF.key_str(v) if k == "key" else v)

@@ -24,13 +24,23 @@ callable includes XLA compilation; callers mark it via
 execution instead of blaming the hot path for one-off trace+compile cost.
 
 Span records carry ``ts`` (seconds since the tracer's ``start_unix``,
-monotonic clock), ``dur``, ``depth`` (per-thread nesting), ``attrs``, and
-``error`` (exception class name) when the body raised — the span is
-emitted either way and the exception propagates untouched.
+monotonic clock), ``dur``, ``depth`` (per-thread nesting), ``id`` (a
+per-tracer sequence number), ``parent`` (the ``id`` of the enclosing span
+on the same thread, or null), ``attrs``, and ``error`` (exception class
+name) when the body raised — the span is emitted either way and the
+exception propagates untouched.
+
+While a tracer is installed every span also enters a
+``jax.profiler.TraceAnnotation`` of its name, so a profile taken with
+``jax.profiler.trace`` shows the program's spans on its host plane, on the
+same clock as the device's operations. The annotation carries the name
+only; attrs stay in the JSONL. jax is imported on a tracer's first span,
+never by importing this module, and never while tracing is off.
 """
 from __future__ import annotations
 
 import atexit
+import itertools
 import json
 import os
 import threading
@@ -56,6 +66,8 @@ class Tracer:
         self._buf: List[str] = []
         self._local = threading.local()
         self._seen_first: set = set()
+        self._ids = itertools.count()
+        self._annotation = None        # jax.profiler.TraceAnnotation, lazily
         self.start_unix = time.time()
         self._t0 = time.monotonic()
         self.records = 0
@@ -67,11 +79,20 @@ class Tracer:
     def now(self) -> float:
         return time.monotonic() - self._t0
 
-    def _depth_stack(self) -> List[str]:
+    def _id_stack(self) -> List[int]:
+        """Ids of this thread's open spans, innermost last."""
         st = getattr(self._local, "stack", None)
         if st is None:
             st = self._local.stack = []
         return st
+
+    def annotation(self, name: str):
+        """A profiler annotation of ``name`` (jax imported on first use)."""
+        cls = self._annotation
+        if cls is None:
+            from jax.profiler import TraceAnnotation
+            cls = self._annotation = TraceAnnotation
+        return cls(name)
 
     def _emit(self, rec: Dict[str, Any]) -> None:
         line = json.dumps(rec, separators=(",", ":"), default=str)
@@ -122,7 +143,8 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("_t", "name", "attrs", "_start", "_depth")
+    __slots__ = ("_t", "name", "attrs", "_start", "_depth", "_id",
+                 "_parent", "_note")
 
     def __init__(self, tracer: Tracer, name: str, attrs: Dict[str, Any]):
         self._t = tracer
@@ -135,20 +157,27 @@ class _Span:
         return self
 
     def __enter__(self):
-        st = self._t._depth_stack()
+        t = self._t
+        st = t._id_stack()
         self._depth = len(st)
-        st.append(self.name)
-        self._start = self._t.now()
+        self._parent = st[-1] if st else None
+        self._id = next(t._ids)
+        st.append(self._id)
+        self._note = t.annotation(self.name)
+        self._note.__enter__()
+        self._start = t.now()
         return self
 
     def __exit__(self, etype, evalue, tb):
         dur = self._t.now() - self._start
-        st = self._t._depth_stack()
-        if st and st[-1] == self.name:
+        self._note.__exit__(etype, evalue, tb)
+        st = self._t._id_stack()
+        if st and st[-1] == self._id:
             st.pop()
         rec = {"kind": "span", "name": self.name,
                "ts": round(self._start, 6), "dur": round(dur, 6),
-               "depth": self._depth}
+               "depth": self._depth, "id": self._id,
+               "parent": self._parent}
         if self.attrs:
             rec["attrs"] = self.attrs
         if etype is not None:
