@@ -12,10 +12,11 @@ four distinct Pareto-front circuits.
     python chip_smoke.py
 
 It fails (non-zero exit, no result line) without a TPU, when any candidate
-was quarantined, when the engine and the oracle differ anywhere, or when
-the front is empty or holds a non-finite value. The last line of a passing
-run is one JSON object naming the device. The seconds it prints are chip
-set-up including compilation, not a benchmark.
+was quarantined, when an evaluated candidate was compiled other than from
+the population's bespoke launch, when the engine and the oracle differ
+anywhere, or when the front is empty or holds a non-finite value. The last
+line of a passing run is one JSON object naming the device. The seconds it
+prints are chip set-up including compilation, not a benchmark.
 """
 from __future__ import annotations
 
@@ -111,8 +112,11 @@ def main() -> int:
         print(f"phase {phase}: {s!r} s wall-clock "
               "(chip set-up including compile, not a benchmark)")
     print(f"backend compiles: {cc.compiles} ({cc.compile_s!r} s)")
+    evaluated = counters.get("eval.specs_evaluated", 0)
+    prefetched = counters.get("eval.bespoke_prefetched", 0)
     print(f"evaluations: {res['n_evaluations']} unique GA specs, "
-          f"{counters.get('eval.specs_evaluated', 0)} specs evaluated, "
+          f"{evaluated} specs evaluated, {prefetched} compiled from the "
+          f"population's bespoke launch, "
           f"quarantines: {sum(quarantined.values())}")
     print(f"oracle: {oracle_equal}/{len(nets)} front circuits equal, "
           f"{operands_differing} comparator operands differing over "
@@ -126,6 +130,9 @@ def main() -> int:
 
     if quarantined:
         return _fail(f"quarantined candidates: {quarantined}")
+    if prefetched != evaluated:
+        return _fail(f"{prefetched} bespoke compiles from the population "
+                     f"launch for {evaluated} evaluated candidates")
     if oracle_equal != len(nets):
         return _fail(f"engine and oracle differ on "
                      f"{len(nets) - oracle_equal} of {len(nets)} circuits")

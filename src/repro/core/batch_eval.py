@@ -140,27 +140,39 @@ def _padded_kmeans_1d(x: jnp.ndarray, k: jnp.ndarray, k_max: int,
     return cent, a
 
 
+def _kmeans_rows(w: jnp.ndarray, k: jnp.ndarray, k_max: int):
+    """Per-input-row padded k-means of a (d_in, d_out) layer with traced k
+    -> (centroids (d_in, k_max), idx (d_in, d_out) int32); k == 0 runs at
+    k = 1 for the caller to select away."""
+    keff = jnp.maximum(k, 1)
+    return jax.vmap(lambda row: _padded_kmeans_1d(row, keff, k_max))(w)
+
+
 def _cluster_dyn(w: jnp.ndarray, k: jnp.ndarray, k_max: int = K_MAX):
     """Per-input cluster STE with traced k; k == 0 -> identity."""
     wd = jax.lax.stop_gradient(w)
-    keff = jnp.maximum(k, 1)
-    cent, idx = jax.vmap(
-        lambda row: _padded_kmeans_1d(row, keff, k_max))(wd)
+    cent, idx = _kmeans_rows(wd, k, k_max)
     wq = jnp.take_along_axis(cent, idx, axis=1)
     return w + jnp.where(k > 0, wq - wd, 0.0)
 
 
-def _quant_dyn(w: jnp.ndarray, bits: jnp.ndarray):
-    """Symmetric per-tensor fake-quant STE with traced bits; 0 -> identity.
-    qmax is built by integer shift so traced bits give the exact same grid
-    as `quantization.fake_quant`'s static python-float 2**(b-1)-1."""
-    wd = jax.lax.stop_gradient(w)
-    beff = jnp.maximum(bits, 2)
-    qmax = ((jnp.left_shift(jnp.int32(1), beff - 1)) - 1).astype(jnp.float32)
-    amax = jnp.maximum(jnp.max(jnp.abs(wd)), 1e-8)
+def _quant_grid(w: jnp.ndarray, bits: jnp.ndarray):
+    """Symmetric per-tensor quantizer with traced bits (>= 2) -> (q, scale),
+    q float32 on the integer grid. qmax is built by integer shift so traced
+    bits give the exact same grid as `quantization.quantize_int`'s static
+    python-float 2**(b-1)-1."""
+    qmax = ((jnp.left_shift(jnp.int32(1), bits - 1)) - 1).astype(jnp.float32)
+    amax = jnp.maximum(jnp.max(jnp.abs(w)), 1e-8)
     scale = amax / qmax
-    fq = jnp.clip(jnp.round(wd / scale), -qmax, qmax) * scale
-    return w + jnp.where(bits > 0, fq - wd, 0.0)
+    return jnp.clip(jnp.round(w / scale), -qmax, qmax), scale
+
+
+def _quant_dyn(w: jnp.ndarray, bits: jnp.ndarray):
+    """Symmetric per-tensor fake-quant STE with traced bits; 0 -> identity
+    (the forward of `quantization.fake_quant`)."""
+    wd = jax.lax.stop_gradient(w)
+    q, scale = _quant_grid(wd, jnp.maximum(bits, 2))
+    return w + jnp.where(bits > 0, q * scale - wd, 0.0)
 
 
 # ---------------------------------------------------------------------------
@@ -229,6 +241,60 @@ def _population_finetune(params0, bits, ks, masks, x, y, *,
         return MZ._train(params0, x, y, epochs=epochs, lr=lr, w_transform=t)
 
     return jax.vmap(train_one, in_axes=(0, 0, 0))(bits, ks, masks)
+
+
+# ---------------------------------------------------------------------------
+# the bespoke compile's device half (one launch for the whole population)
+# ---------------------------------------------------------------------------
+
+
+@functools.partial(jax.jit, static_argnames=("k_max",))
+def _population_bespoke(trained, bits, ks, masks, *, k_max: int = K_MAX):
+    """Device half of `minimize.compile_bespoke` for P candidates in one
+    launch, on the trained population where the finetune left it.
+
+    trained: params pytree with a leading population axis; bits/ks: (P, L)
+    int32, 0 = off; masks: as `_population_finetune`'s, applied first.
+    Returns per layer a dict of (P, ...) arrays: "cb" (d_in, k_max) float32
+    and "idx" (d_in, d_out) int32, the per-input k-means the finetune
+    trains against (valid slots < k); "q" (d_in, d_out) int32 and "scale"
+    float32, the symmetric per-tensor quantizer at bits (0 -> 8, the serial
+    default). Both are computed for every candidate; the host snap
+    (`minimize._snap_bespoke`) reads the one its spec asks for.
+    """
+    def one(layers, bits_row, ks_row, masks_row):
+        out = []
+        for i, layer in enumerate(layers):
+            w = layer["w"] * masks_row[i]
+            cb, idx = _kmeans_rows(w, ks_row[i], k_max)
+            q, scale = _quant_grid(
+                w, jnp.where(bits_row[i] > 0, bits_row[i], 8))
+            out.append({"cb": cb, "idx": idx, "q": q.astype(jnp.int32),
+                        "scale": scale})
+        return out
+
+    return jax.vmap(one)(trained["layers"], bits, ks, masks)
+
+
+def _bespoke_k_max(ks: np.ndarray) -> int:
+    """k-means slots of the bespoke launch: `K_MAX`, more if a spec asks."""
+    return max(K_MAX, int(ks.max(initial=0)))
+
+
+def bespoke_one(params, spec: ModelMin, masks):
+    """`_population_bespoke` at P = 1, the serial `minimize.compile_bespoke`'s
+    device half: -> the candidate's outputs on the host. ``masks``: per
+    layer a mask or None, as `minimize.make_masks` gives them."""
+    bits, ks = stack_specs([spec])
+    one = jax.tree_util.tree_map(lambda a: np.asarray(a, np.float32)[None],
+                                 params)
+    stacked = tuple(
+        np.asarray(np.ones(layer["w"].shape[1:]) if m is None else m,
+                   np.float32)[None]
+        for layer, m in zip(one["layers"], masks))
+    out = _population_bespoke(one, bits, ks, stacked,
+                              k_max=_bespoke_k_max(ks))
+    return jax.tree_util.tree_map(lambda a: a[0], jax.device_get(out))
 
 
 # ---------------------------------------------------------------------------
@@ -455,21 +521,26 @@ def _packed_netlist_for(key: Optional[str], net, NS):
 
 
 def _compile_and_price(params_pop, specs, masks_serial, xte, yte, *,
+                       bespoke,
                        netlist: bool = True,
                        quarantine: Optional[List[QuarantineRecord]] = None,
                        pack_key: Optional[Callable[[ModelMin], str]] = None
                        ) -> List[MZ.EvalResult]:
     """Host-side bespoke compile per candidate + one vectorized pricing
-    call for the whole population. Every candidate is additionally lowered
-    to its bespoke netlist (`repro.circuit`) for the critical-path delay;
-    with ``netlist=True`` (the default objective) the accuracy is the
-    netlist-exact simulation of the printed datapath instead of the float
-    emulation (area/power stay on the analytic pricing, which the
-    structural netlist cost is tested to reproduce exactly). All exact
-    netlist-mode candidates are scored in ONE packed-population launch
-    through `repro.kernels.netlist_sim` — per-candidate node tables are
-    cached under ``pack_key(spec)`` alongside the EvalCache keyspace, so a
-    GA revisiting genomes repacks nothing.
+    call for the whole population. ``params_pop`` and ``bespoke`` (the
+    fetched outputs of `_population_bespoke`) are host arrays with a
+    leading population axis, so the per-candidate compile is numpy on
+    them (`minimize.compile_bespoke(..., prefetched=...)`). Every
+    candidate is additionally lowered to its bespoke netlist
+    (`repro.circuit`) for the critical-path delay; with ``netlist=True``
+    (the default objective) the accuracy is the netlist-exact simulation
+    of the printed datapath instead of the float emulation (area/power
+    stay on the analytic pricing, which the structural netlist cost is
+    tested to reproduce exactly). All exact netlist-mode candidates are
+    scored in ONE packed-population launch through
+    `repro.kernels.netlist_sim` — per-candidate node tables are cached
+    under ``pack_key(spec)`` alongside the EvalCache keyspace, so a GA
+    revisiting genomes repacks nothing.
 
     Candidates carrying approximation genes (`ModelMin.has_approx`) are
     scored by `approx.evaluate_netlist` — the one shared policy with the
@@ -506,9 +577,10 @@ def _compile_and_price(params_pop, specs, masks_serial, xte, yte, *,
                 if _EVAL_FAULT_HOOK is not None:
                     _EVAL_FAULT_HOOK(spec, attempt)
                 stage = "compile"
-                params_p = jax.tree_util.tree_map(lambda a, p=p: a[p],
-                                                  params_pop)
-                c = MZ.compile_bespoke(params_p, spec, masks_serial[p])
+                params_p, bespoke_p = jax.tree_util.tree_map(
+                    lambda a, p=p: a[p], (params_pop, bespoke))
+                c = MZ.compile_bespoke(params_p, spec, masks_serial[p],
+                                       prefetched=bespoke_p)
                 net = CIRC.compile_netlist(c)
                 stage = "score"
                 if spec.has_approx:
@@ -662,7 +734,8 @@ def _evaluate_unique(cfg: PrintedMLPConfig, specs: List[ModelMin], *,
                      ) -> Dict[str, MZ.EvalResult]:
     """`evaluate_population`'s body: spec json -> result, each distinct
     spec once. One span per stage: ``eval.prepare`` (lookup, staging,
-    uploads), ``eval.finetune``, ``eval.fetch``, ``eval.compile_price``."""
+    uploads), ``eval.finetune``, ``eval.fetch`` (the bespoke launch's
+    dispatch and the one device-to-host copy), ``eval.compile_price``."""
     with TR.span("eval.prepare"):
         from repro.verify.diagnostics import verify_enabled
         if specs and verify_enabled():
@@ -739,8 +812,14 @@ def _evaluate_unique(cfg: PrintedMLPConfig, specs: List[ModelMin], *,
                 trained = _population_finetune(*args, **kw)
                 jax.block_until_ready(trained)
         with TR.span("eval.fetch", n=n_real):
-            trained = jax.tree_util.tree_map(
-                lambda a: np.asarray(a[:n_real]), trained)
+            # the bespoke compile's device half for the whole population,
+            # queued behind the finetune before anything syncs, then ONE
+            # device-to-host copy of both; the bucket padding is cut on
+            # the host
+            bespoke = _population_bespoke(trained, bits_j, ks_j, masks,
+                                          k_max=_bespoke_k_max(ks))
+            trained, bespoke = jax.tree_util.tree_map(
+                lambda a: a[:n_real], jax.device_get((trained, bespoke)))
         recs: List[QuarantineRecord] = []
 
         def pack_key(s: ModelMin) -> str:
@@ -750,7 +829,8 @@ def _evaluate_unique(cfg: PrintedMLPConfig, specs: List[ModelMin], *,
         with TR.span("eval.compile_price", dataset=cfg.name, n=n_real):
             priced = _compile_and_price(trained, todo,
                                         masks_serial[:n_real],
-                                        xte, yte, netlist=netlist,
+                                        xte, yte, bespoke=bespoke,
+                                        netlist=netlist,
                                         quarantine=recs,
                                         pack_key=pack_key)
         for r in priced:

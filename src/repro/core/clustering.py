@@ -67,12 +67,10 @@ def cluster_per_input(w: jnp.ndarray, k: int, iters: int = 25):
     """Paper's multiplier-sharing form: k-means per input row.
     w: (d_in, d_out). Returns (codebooks (d_in, k), idx (d_in, d_out)).
 
-    Jitted with static (k, iters): called eagerly per candidate layer from
-    ``minimize.compile_bespoke``, an un-jitted entry would retrace the Lloyd
-    ``lax.scan`` on EVERY call and re-enter the backend compiler each warm
-    GA generation (found by the executable observatory — the netlist_bench
-    zero-compile gate attributed ~14 backend compiles per generation to
-    this site). Static k/iters keep one executable per (shape, k)."""
+    Jitted with static (k, iters), so an eager call (``clustering_error``)
+    builds one executable per (shape, k) instead of retracing the Lloyd
+    ``lax.scan`` on every call (the netlist_bench zero-compile gate once
+    attributed ~14 backend compiles per generation to this site)."""
     f = jax.vmap(lambda row: _kmeans_1d(row, k=k, iters=iters))
     cent, a = f(w.astype(jnp.float32))
     return cent, a

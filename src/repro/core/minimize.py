@@ -29,6 +29,7 @@ from repro.core import quantization as Q
 from repro.core.compression_spec import LayerMin, ModelMin
 from repro.data.uci import dataset_for
 from repro.nn import mlp as M
+from repro.obs import metrics as MT
 from repro.obs import trace as TR
 
 
@@ -131,22 +132,35 @@ class CompiledMLP:
         return out
 
 
-def compile_bespoke(params, spec: ModelMin, masks) -> CompiledMLP:
+def compile_bespoke(params, spec: ModelMin, masks,
+                    prefetched=None) -> CompiledMLP:
+    """Integer weights, codebooks and scales of one candidate.
+
+    The device half, the per-input k-means and the quantizer, is
+    `batch_eval._population_bespoke`; the rest is numpy on its outputs
+    (`_snap_bespoke`). ``prefetched`` is this candidate's slice of that
+    launch's fetched outputs, when the population path ran it for the
+    whole population (counted in ``eval.bespoke_prefetched``); without it
+    the launch runs here at P = 1."""
     with TR.span("minimize.compile_bespoke"):
-        return _compile_bespoke(params, spec, masks)
+        if prefetched is None:
+            from repro.core import batch_eval as BE  # lazy: imports us
+            prefetched = BE.bespoke_one(params, spec, masks)
+        else:
+            MT.counter("eval.bespoke_prefetched").inc()
+        return _snap_bespoke(params, spec, masks, prefetched)
 
 
-def _compile_bespoke(params, spec: ModelMin, masks) -> CompiledMLP:
+def _snap_bespoke(params, spec: ModelMin, masks, dev) -> CompiledMLP:
+    """Host half of `compile_bespoke`: snap codebooks to the fixed-point
+    grid, re-apply pruning zeros, take the biases. ``dev``: per layer the
+    candidate's `batch_eval._population_bespoke` outputs, host arrays."""
     q_layers, scales, biases, clusters, w_bits = [], [], [], [], []
     for i, layer in enumerate(params["layers"]):
         lm = spec.layers[i]
         bits = lm.bits if lm.bits is not None else 8
-        w = np.asarray(layer["w"], np.float32)
-        if masks[i] is not None:
-            w = w * np.asarray(masks[i], np.float32)
         if lm.clusters is not None:
-            cb, idx = C.cluster_per_input(jnp.asarray(w), lm.clusters)
-            cb, idx = np.asarray(cb), np.asarray(idx)
+            cb, idx = dev[i]["cb"][:, :lm.clusters], dev[i]["idx"]
             w_rec = np.take_along_axis(cb, idx, axis=1)
             # snap codebooks to the fixed-point grid
             qmax = 2 ** (bits - 1) - 1
@@ -158,8 +172,7 @@ def _compile_bespoke(params, spec: ModelMin, masks) -> CompiledMLP:
                 q = q * np.asarray(masks[i], np.int64)
             clusters.append((idx, cb_q))
         else:
-            qj, sj = Q.quantize_int(jnp.asarray(w), Q.QuantConfig(bits=bits))
-            q, s = np.asarray(qj, np.int64), float(np.asarray(sj))
+            q, s = np.asarray(dev[i]["q"], np.int64), float(dev[i]["scale"])
             clusters.append(None)
         q_layers.append(q)
         scales.append(float(s))

@@ -130,6 +130,159 @@ def test_padded_kmeans_matches_static_k():
 
 
 # ---------------------------------------------------------------------------
+# the bespoke compile in one population launch
+# ---------------------------------------------------------------------------
+
+BITS_GENES = (None, 2, 3, 4, 5, 6, 7, 8)
+CLUSTER_GENES = (None, 2, 3, 4, 6, 8, 12, 16)
+
+
+def _serial_recipe(params, spec, masks):
+    """The bespoke compile as it was before the population launch: an eager
+    `cluster_per_input` or `quantize_int` per layer, then the host snap."""
+    import jax.numpy as jnp
+    from repro.core import clustering as C
+    from repro.core import quantization as Q
+    q_layers, scales, biases, clusters, w_bits = [], [], [], [], []
+    for i, layer in enumerate(params["layers"]):
+        lm = spec.layers[i]
+        bits = lm.bits if lm.bits is not None else 8
+        w = np.asarray(layer["w"], np.float32)
+        if masks[i] is not None:
+            w = w * np.asarray(masks[i], np.float32)
+        if lm.clusters is not None:
+            cb, idx = C.cluster_per_input(jnp.asarray(w), lm.clusters)
+            cb, idx = np.asarray(cb), np.asarray(idx)
+            w_rec = np.take_along_axis(cb, idx, axis=1)
+            qmax = 2 ** (bits - 1) - 1
+            s = max(np.abs(w_rec).max(), 1e-8) / qmax
+            cb_q = np.clip(np.round(cb / s), -qmax, qmax).astype(np.int64)
+            q = np.take_along_axis(cb_q, idx, axis=1)
+            if masks[i] is not None:
+                q = q * np.asarray(masks[i], np.int64)
+            clusters.append((idx, cb_q))
+        else:
+            qj, sj = Q.quantize_int(jnp.asarray(w), Q.QuantConfig(bits=bits))
+            q, s = np.asarray(qj, np.int64), float(np.asarray(sj))
+            clusters.append(None)
+        q_layers.append(q)
+        scales.append(float(s))
+        biases.append(np.asarray(layer["b"], np.float32))
+        w_bits.append(bits)
+    return MZ.CompiledMLP(q_layers, scales, biases, clusters, w_bits,
+                          spec.input_bits)
+
+
+def _bespoke_population(dims, n_real, seed=0):
+    """A seeded trained-population stand-in at ``dims``, padded to its
+    power-of-two bucket as the evaluator pads: every bits and cluster gene
+    on every layer, sparsity 0 and 0.3. -> (params with the bucket axis,
+    specs, per-candidate masks, the launch's fetched outputs)."""
+    import jax
+    rng = np.random.default_rng(seed)
+    n_layers = len(dims) - 1
+    specs = [ModelMin(tuple(
+        LayerMin(BITS_GENES[(p + 3 * i) % 8], (0.0, 0.3)[(p + i) % 2],
+                 CLUSTER_GENES[(3 * p + i) % 8]) for i in range(n_layers)),
+        4) for p in range(n_real)]
+    bucket = 1 << (n_real - 1).bit_length()
+    padded = specs + [specs[-1]] * (bucket - n_real)
+    params = {"layers": tuple(
+        {"w": rng.normal(size=(bucket, a, b)).astype(np.float32),
+         "b": rng.normal(size=(bucket, b)).astype(np.float32)}
+        for a, b in zip(dims[:-1], dims[1:]))}
+    masks = [[np.asarray(MZ.P.magnitude_mask(layer["w"][p],
+                                             s.layers[i].sparsity), bool)
+              if s.layers[i].sparsity > 0 else None
+              for i, layer in enumerate(params["layers"])]
+             for p, s in enumerate(padded)]
+    stacked = tuple(np.stack([np.ones(layer["w"].shape[1:], np.float32)
+                              if m[i] is None else m[i].astype(np.float32)
+                              for m in masks])
+                    for i, layer in enumerate(params["layers"]))
+    bits, ks = BE.stack_specs(padded)
+    out = BE._population_bespoke(params, bits, ks, stacked,
+                                 k_max=BE._bespoke_k_max(ks))
+    params, out = jax.tree_util.tree_map(lambda a: a[:n_real],
+                                         jax.device_get((params, out)))
+    return params, specs, masks[:n_real], out
+
+
+def _candidate(tree, p):
+    import jax
+    return jax.tree_util.tree_map(lambda a: a[p], tree)
+
+
+def _assert_same_compiled(got, want, *, every_slot):
+    """Exact equality of two `CompiledMLP`s. With ``every_slot=False`` the
+    integer codebooks are compared on the slots some weight indexes, the
+    only ones the netlist and the pricing read: a slot no weight takes
+    keeps its quantile initialisation, which the static-k and the padded
+    k-means interpolate a few ulps apart, and a value on a half of the grid
+    can then round either way."""
+    assert got.w_bits == want.w_bits
+    assert got.scales == want.scales
+    assert got.input_bits == want.input_bits
+    for a, b in zip(got.q_layers + got.biases, want.q_layers + want.biases):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(a, b)
+    for a, b in zip(got.clusters, want.clusters):
+        assert (a is None) == (b is None)
+        if a is None:
+            continue
+        (ia, ca), (ib, cb) = a, b
+        assert ia.dtype == ib.dtype and ca.dtype == cb.dtype
+        np.testing.assert_array_equal(ia, ib)
+        assert ca.shape == cb.shape
+        read = np.ones(ca.shape, bool)
+        if not every_slot:
+            read = np.zeros(ca.shape, bool)
+            np.put_along_axis(read, ia, True, axis=1)
+        np.testing.assert_array_equal(ca[read], cb[read])
+
+
+@pytest.mark.parametrize("dims", [(11, 4, 7), (16, 5, 10)])
+def test_population_bespoke_matches_serial_recipe(dims):
+    """The population launch plus the host snap gives the integers the
+    eager per-layer recipe gave, and P = 1 gives what P = n gives."""
+    params, specs, masks, out = _bespoke_population(dims, n_real=13)
+    for p, spec in enumerate(specs):
+        pp = _candidate(params, p)
+        got = MZ.compile_bespoke(pp, spec, masks[p],
+                                 prefetched=_candidate(out, p))
+        _assert_same_compiled(got, _serial_recipe(pp, spec, masks[p]),
+                              every_slot=False)
+        _assert_same_compiled(MZ.compile_bespoke(pp, spec, masks[p]), got,
+                              every_slot=True)
+
+
+def test_prefetched_bespoke_stays_on_the_host():
+    """The per-candidate compile from the launch's outputs moves nothing
+    between host and device; ``eval.bespoke_prefetched`` counts it once
+    per candidate, in the evaluator too, and never on the serial path."""
+    import jax
+    from repro.obs import metrics as MT
+    prefetched = MT.counter("eval.bespoke_prefetched")
+    params, specs, masks, out = _bespoke_population((11, 4, 7), n_real=6)
+    before = prefetched.value
+    with jax.transfer_guard("disallow"):
+        for p, spec in enumerate(specs):
+            MZ.compile_bespoke(_candidate(params, p), spec, masks[p],
+                               prefetched=_candidate(out, p))
+    assert prefetched.value - before == len(specs)
+
+    before = prefetched.value
+    MZ.compile_bespoke(_candidate(params, 0), specs[0], masks[0])
+    assert prefetched.value == before
+
+    evaluated = MT.counter("eval.specs_evaluated")
+    before, n_before = prefetched.value, evaluated.value
+    BE.evaluate_population(CFG, SPECS + SPECS[:2], epochs=30)
+    assert evaluated.value - n_before == len(SPECS)
+    assert prefetched.value - before == len(SPECS)
+
+
+# ---------------------------------------------------------------------------
 # persistent cache
 # ---------------------------------------------------------------------------
 

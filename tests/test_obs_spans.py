@@ -198,3 +198,18 @@ def test_device_readers_match_the_executable_names():
     assert not re.search(NETLIST_PATTERN, ft_name), ft_name
     assert re.search(NETLIST_PATTERN, nl_name), nl_name
     assert not re.search(FINETUNE_PATTERN, nl_name), nl_name
+
+
+def test_bespoke_launch_is_named_apart_from_the_device_readers():
+    """The bespoke compile's population launch is read only through
+    `breakdown.device_ops`: neither device reader may count it."""
+    f32, i32 = jnp.float32, jnp.int32
+    trained = {"layers": ({"w": _sds((2, 3, 4), f32), "b": _sds((2, 4), f32)},
+                          {"w": _sds((2, 4, 2), f32),
+                           "b": _sds((2, 2), f32)})}
+    masks = (_sds((2, 3, 4), f32), _sds((2, 4, 2), f32))
+    name = _module_name(BE._population_bespoke.lower(
+        trained, _sds((2, 2), i32), _sds((2, 2), i32), masks))
+    assert "bespoke" in name, name
+    assert not re.search(FINETUNE_PATTERN, name), name
+    assert not re.search(NETLIST_PATTERN, name), name

@@ -109,3 +109,26 @@ def test_population_finetune_compiles_for_v5e(one_chip):
     w0 = compiled.out_info["layers"][0]["w"]
     assert w0.shape == (P, CFG.n_features, CFG.hidden[0])
     assert np.dtype(w0.dtype) == np.float32
+
+
+def test_population_bespoke_compiles_for_v5e(one_chip):
+    n_layers = len(CFG.layer_dims) - 1
+    dims = list(zip(CFG.layer_dims[:-1], CFG.layer_dims[1:]))
+    trained = {"layers": tuple(
+        {"w": _sds((P, d_in, d_out), jnp.float32, one_chip),
+         "b": _sds((P, d_out), jnp.float32, one_chip)}
+        for d_in, d_out in dims)}
+    masks = tuple(_sds((P, d_in, d_out), jnp.float32, one_chip)
+                  for d_in, d_out in dims)
+    compiled = BE._population_bespoke.lower(
+        trained,
+        _sds((P, n_layers), jnp.int32, one_chip),
+        _sds((P, n_layers), jnp.int32, one_chip),
+        masks).compile()
+    out = compiled.out_info
+    assert len(out) == n_layers
+    for layer, (d_in, d_out) in zip(out, dims):
+        assert layer["cb"].shape == (P, d_in, BE.K_MAX)
+        assert layer["idx"].shape == layer["q"].shape == (P, d_in, d_out)
+        assert np.dtype(layer["q"].dtype) == np.int32
+        assert layer["scale"].shape == (P,)
