@@ -17,7 +17,7 @@ CONFIG_KEYS = {"name", "source", "layer_dims", "baseline_bits",
                "input_bits", "netlist_warmup", "precision", "assumed"}
 MIX_KEYS = {"name", "about", "seeds", "population", "generations", "epochs",
             "objective", "include_delay", "genes", "seed_specs", "warmup",
-            "check"}
+            "check", "netlist_warmup"}
 
 
 @dataclasses.dataclass

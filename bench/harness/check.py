@@ -10,10 +10,14 @@ fixed-point compile, integer circuit, price) and the run compares:
 * ``acc_gap``: the widest gap between the program's accuracy and the
   reference's, as a share of the test rows;
 * ``area_gap``: the widest gap between the two areas, as a share of the
-  reference's area.
+  reference's area;
+* ``disagree_share``: the share of the sampled answers whose accuracy or
+  area the reference does not reproduce (a gap over ``AGREE``).
 
-The sample holds the largest circuit of the window and ``n - 1`` more drawn
-from the seed. Quarantined candidates are left out: they count as failed.
+A cell's limits file names the numbers it compares, each with its limit;
+the others are printed beside them as facts. The sample holds the largest
+circuit of the window and ``n - 1`` more drawn from the seed. Quarantined
+candidates are left out: they count as failed.
 """
 from __future__ import annotations
 
@@ -23,6 +27,10 @@ from typing import Dict, List, Tuple
 import numpy as np
 
 from harness.jobs import STREAM_CHECK, Window, derive
+
+# Below this an answer agrees: areas are float sums of the same integer
+# terms, and one test row moves an accuracy by 1 / test rows.
+AGREE = 1e-9
 
 
 def sample(window: Window, n: int, seed: int) -> List[Tuple[str, object]]:
@@ -38,13 +46,37 @@ def sample(window: Window, n: int, seed: int) -> List[Tuple[str, object]]:
     return [done[largest]] + [done[rest[i]] for i in sorted(pick)]
 
 
+def per_answer(answers: List[Dict], reference: List[Dict]
+               ) -> List[Tuple[float, float]]:
+    """(accuracy gap, relative area gap) of each paired evaluation."""
+    return [(abs(a["accuracy"] - r["accuracy"]),
+             abs(a["area_mm2"] - r["area_mm2"]) / r["area_mm2"])
+            for a, r in zip(answers, reference)]
+
+
 def gaps(answers: List[Dict], reference: List[Dict]) -> Dict[str, float]:
-    """Widest accuracy and relative-area gaps over paired evaluations."""
-    acc = max(abs(a["accuracy"] - r["accuracy"])
-              for a, r in zip(answers, reference))
-    area = max(abs(a["area_mm2"] - r["area_mm2"]) / r["area_mm2"]
-               for a, r in zip(answers, reference))
-    return {"acc_gap": float(acc), "area_gap": float(area)}
+    """Every number the check can compare, over paired evaluations."""
+    each = per_answer(answers, reference)
+    return {"acc_gap": float(max(a for a, _ in each)),
+            "area_gap": float(max(r for _, r in each)),
+            "disagree_share": sum(max(a, r) > AGREE for a, r in each)
+            / len(each)}
+
+
+def limited(limits: Dict) -> Dict[str, float]:
+    """The numbers a cell compares: its limits file's numeric entries."""
+    return {k: v for k, v in limits.items()
+            if isinstance(v, (int, float)) and not isinstance(v, bool)}
+
+
+def judge(found: Dict[str, float], limits: Dict) -> Tuple[bool, Dict]:
+    """-> (every compared number within its limit, {name: {"value",
+    "limit"}})."""
+    numbers = {k: {"value": found[k], "limit": v}
+               for k, v in limited(limits).items()}
+    if not numbers:
+        raise KeyError("the limits name no number to compare")
+    return all(n["value"] <= n["limit"] for n in numbers.values()), numbers
 
 
 def reference_answers(config: Dict, mix: Dict, data_seed: int,
@@ -68,10 +100,10 @@ def run_check(config: Dict, mix: Dict, limits: Dict, window: Window,
                for _, r in picked]
     ref = reference_answers(config, mix, data_seed, [s for s, _ in picked])
     found = gaps(answers, ref)
-    numbers = {k: {"value": v, "limit": limits[k]} for k, v in found.items()}
-    correct = all(v <= limits[k] for k, v in found.items())
-    return correct, numbers, {"sampled": len(picked),
-                              "reference_s": time.perf_counter() - t0}
+    correct, numbers = judge(found, limits)
+    facts = {k: v for k, v in found.items() if k not in numbers}
+    return correct, numbers, dict(facts, sampled=len(picked),
+                                  reference_s=time.perf_counter() - t0)
 
 
 def control_answers(config: Dict, mix: Dict, data_seed: int,

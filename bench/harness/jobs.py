@@ -6,7 +6,10 @@ make_batch_evaluator(cfg, epochs, seed, cache=None, netlist=...))`` with
 the mix's gene choices and warm-start specs, and a GA seed of its own.
 A generation is one evaluation of the initial population, or one
 ``on_generation`` step after it. The window opens at a job's start and
-closes at the first generation boundary after ``seconds``.
+closes at the first generation boundary after ``seconds``, or after a
+fixed number of generations where one is given (the readings of
+`bench/calibrate.py` and the CPU tests, whose work then does not depend
+on the machine's speed).
 """
 from __future__ import annotations
 
@@ -44,6 +47,7 @@ class Batch:
 class Window:
     """What the window did, on the host clock."""
     seconds: float
+    max_generations: Optional[int] = None   # closes it sooner, if set
     t0: float = 0.0
     t1: float = 0.0
     open: bool = False
@@ -132,7 +136,8 @@ class Search:
             if gen_note[0] is not None:
                 gen_note[0].__exit__(None, None, None)
                 gen_note[0] = None
-            if now - window.t0 >= window.seconds:
+            if now - window.t0 >= window.seconds or \
+                    window.generations == window.max_generations:
                 window.t1 = now
                 window.open = False
                 raise WindowClosed

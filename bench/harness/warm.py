@@ -7,9 +7,9 @@ candidates happen to lower to, so warm-up search jobs miss some. Here each
 reachable combination is launched once through the program's public
 ``simulate_population``, on stand-in netlists built to its size: a chain of
 ``levels`` additions (one wave each) and constants up to ``nodes`` slots.
-The ranges come from the configuration's ``netlist_warmup``: the node
-count of one candidate and the waves of one launch, as the cell's searches
-produce them.
+The ranges come from ``netlist_warmup``: the node count of one candidate
+and the waves of one launch, as the cell's searches produce them. They are
+the traffic mix's where it has them, else the configuration's.
 """
 from __future__ import annotations
 
@@ -60,17 +60,21 @@ def stand_in(ir, n_in: int, n_cls: int, in_bits: int, levels: int,
     return net
 
 
-def netlist_buckets(program, config: Dict, population: int,
-                    test_rows: int) -> int:
-    """Launch each reachable bucket once on ``test_rows`` rows; -> the
-    number of launches."""
+def warm_range(config: Dict, mix: Dict) -> Dict:
+    """The cell's ``netlist_warmup``: the mix's, else the configuration's."""
+    return mix.get("netlist_warmup", config["netlist_warmup"])
+
+
+def netlist_buckets(program, config: Dict, mix: Dict, test_rows: int) -> int:
+    """Launch each bucket the cell can reach once on ``test_rows`` rows;
+    -> the number of launches."""
     dims = config["layer_dims"]
     n_in, n_cls = dims[0], dims[-1]
     x = np.zeros((test_rows, n_in), np.int64)
     packed = {}
     n = 0
-    for p, levels, nodes in shapes(population, config["netlist_warmup"],
-                                   n_in, n_cls):
+    for p, levels, nodes in shapes(mix["population"],
+                                   warm_range(config, mix), n_in, n_cls):
         if (levels, nodes) not in packed:
             packed[(levels, nodes)] = program.NS.pack_netlist(stand_in(
                 program.ir, n_in, n_cls, config["input_bits"], levels,

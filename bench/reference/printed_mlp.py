@@ -7,7 +7,10 @@ and takes nothing the program made. From a configuration file, a seed and
 one genome it builds the synthetic stand-in data, the float pretrain, the
 magnitude masks, the QAT finetune (mask -> per-input k-means -> symmetric
 fake-quant, all straight-through), the fixed-point compile, the exact
-integer forward of the bespoke circuit and its analytic printed price.
+integer forward of the bespoke circuit and its analytic printed price. A
+genome with approximation genes is instead the circuit after its
+approximation passes, simulated and priced structurally
+(`reference/approx.py`).
 
 ``dtype`` is the precision the float parts run in: float32 with the
 configuration's matmul precision for the reference, bfloat16 for the
@@ -232,16 +235,30 @@ def compile_fixed_point(layers, genes, masks):
     return out
 
 
+def integer_inputs(x, input_bits: int) -> np.ndarray:
+    """ADC-quantised features: [0, 1] onto the 2**input_bits - 1 grid."""
+    levels = (1 << input_bits) - 1
+    return np.round(np.asarray(x, np.float32) * levels).astype(np.int64)
+
+
+def integer_biases(comp, input_bits: int) -> List[np.ndarray]:
+    """Each layer's bias rounded onto its accumulator's grid."""
+    alpha = 1.0 / ((1 << input_bits) - 1)
+    out = []
+    for _, s, b, _, _ in comp:
+        alpha *= s
+        out.append(np.round(np.asarray(b, np.float64) / alpha)
+                   .astype(np.int64))
+    return out
+
+
 def integer_accuracy(comp, input_bits: int, x, y) -> float:
     """Accuracy of the bespoke integer circuit: ADC-quantised inputs,
     integer coefficients, biases rounded onto each accumulator's grid,
     ReLU, argmax (first index wins a tie)."""
-    levels = (1 << input_bits) - 1
-    a = np.round(np.asarray(x, np.float32) * levels).astype(np.int64)
-    alpha = 1.0 / levels
-    for i, (q, s, b, _, _) in enumerate(comp):
-        alpha *= s
-        b_int = np.round(np.asarray(b, np.float64) / alpha).astype(np.int64)
+    a = integer_inputs(x, input_bits)
+    for i, ((q, _, _, _, _), b_int) in enumerate(
+            zip(comp, integer_biases(comp, input_bits))):
         pre = a @ q + b_int
         a = np.maximum(pre, 0) if i < len(comp) - 1 else pre
     return float(np.mean(np.argmax(a, axis=1) == np.asarray(y)))
@@ -324,7 +341,10 @@ class Reference:
 
     def evaluate(self, spec_json: str, *, epochs: int,
                  lr: float) -> Dict[str, float]:
-        """One genome (the search's spec JSON) -> accuracy and price."""
+        """One genome (the search's spec JSON) -> accuracy and price; a
+        genome with approximation genes is the circuit after its passes
+        (`reference/approx.py`)."""
+        from . import approx as AX
         spec = json.loads(spec_json)
         genes = tuple((g["bits"], g["clusters"]) for g in spec["layers"])
         w0 = [np.asarray(l["w"], np.float32) for l in self.params0]
@@ -339,7 +359,11 @@ class Reference:
         layers = [(np.asarray(l["w"].astype(jnp.float32)),
                    np.asarray(l["b"].astype(jnp.float32))) for l in trained]
         comp = compile_fixed_point(layers, genes, masks)
-        out = price(comp, spec["input_bits"])
-        out["accuracy"] = integer_accuracy(comp, spec["input_bits"],
-                                           self.xte, self.yte)
+        ib = spec["input_bits"]
+        approx = AX.genes(spec)
+        if approx is not None:
+            return AX.evaluate(comp, ib, approx, integer_inputs(self.xte, ib),
+                               integer_biases(comp, ib), self.yte)
+        out = price(comp, ib)
+        out["accuracy"] = integer_accuracy(comp, ib, self.xte, self.yte)
         return out

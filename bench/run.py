@@ -73,7 +73,7 @@ def setup(search, cell, seed: int, test_rows: int) -> None:
     base = J.derive(seed, J.STREAM_WARMUP)
     for n in range(1, mix["population"] + 1):
         search.evaluate(search.random_specs(n, (base + n) % (1 << 31)), warm)
-    netlist_buckets(search.P, cell.config, mix["population"], test_rows)
+    netlist_buckets(search.P, cell.config, mix, test_rows)
     search.run(warm, lambda j: (base + 1000 + j) % (1 << 31), start=False,
                max_jobs=mix["warmup"]["jobs"])
 
