@@ -32,7 +32,8 @@ from repro.approx.analyze import (decision_error_bound,  # noqa: F401
                                   propagate_errors)
 from repro.approx.measure import measured_max_logit_error  # noqa: F401
 from repro.approx.budget import (ApproxParams, BudgetReport,  # noqa: F401
-                                 approximate, build_passes,
+                                 PricedApprox, approximate,
+                                 approximate_and_price, build_passes,
                                  evaluate_netlist, fit_budget, logit_budget)
 from repro.approx.passes import (RoundCoeffsCSD, SimplifyActs,  # noqa: F401
                                  TruncateAccum, product_info, truncate_csd)

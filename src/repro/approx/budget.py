@@ -68,22 +68,47 @@ def approximate(net: ir.Netlist, p: ApproxParams) -> ir.Netlist:
     return PassManager(build_passes(p)).run(net)
 
 
-def evaluate_netlist(net: ir.Netlist, compiled, spec, xte, yte):
-    """THE scoring policy for a candidate carrying approximation genes,
-    shared by the serial (`minimize.evaluate_spec`) and batched
-    (`batch_eval._compile_and_price`) paths so they can never drift: the
-    printed circuit is the approximated netlist, so accuracy is its
-    bit-exact simulation, area/power the approximation-aware structural
-    pricing, delay its critical path. ``net`` is the candidate's EXACT
-    compiled netlist. Returns a `minimize.EvalResult`."""
-    from repro.circuit.simulate import netlist_accuracy
-    from repro.core import minimize as MZ       # lazy: minimize imports us
+@dataclasses.dataclass(frozen=True)
+class PricedApprox:
+    """A candidate's approximated netlist with its structural price and
+    critical path: everything of its `EvalResult` but the accuracy."""
+    net: ir.Netlist
+    area_mm2: float
+    power_mw: float
+    n_multipliers: int
+    delay_levels: int
 
+    def result(self, spec, accuracy: float):
+        """-> the candidate's `minimize.EvalResult` at ``accuracy``."""
+        from repro.core import minimize as MZ   # lazy: minimize imports us
+        return MZ.EvalResult(spec, accuracy, self.area_mm2, self.power_mw,
+                             self.n_multipliers,
+                             delay_levels=self.delay_levels)
+
+
+def approximate_and_price(net: ir.Netlist, spec) -> PricedApprox:
+    """The host half of scoring a candidate that carries approximation
+    genes: the printed circuit is the approximated netlist, so area/power
+    are its approximation-aware structural pricing and delay its critical
+    path. ``net`` is the candidate's EXACT compiled netlist. Shared by the
+    serial (`evaluate_netlist`) and batched (`batch_eval._compile_and_price`)
+    paths so they can never drift; each then simulates ``.net``."""
     anet = approximate(net, ApproxParams.from_spec(spec))
     sc = COST.structural_cost(anet)
-    return MZ.EvalResult(spec, netlist_accuracy(anet, compiled, xte, yte),
-                         sc.area_mm2, sc.power_mw, sc.n_multipliers,
-                         delay_levels=anet.critical_path_levels())
+    return PricedApprox(anet, sc.area_mm2, sc.power_mw, sc.n_multipliers,
+                        anet.critical_path_levels())
+
+
+def evaluate_netlist(net: ir.Netlist, compiled, spec, xte, yte):
+    """The serial scoring of a candidate carrying approximation genes
+    (`minimize.evaluate_spec`): `approximate_and_price`, then the bit-exact
+    simulation of the approximated netlist in a launch of its own. The
+    batched path scores the same netlist in its population's one launch.
+    Returns a `minimize.EvalResult`."""
+    from repro.circuit.simulate import netlist_accuracy
+
+    a = approximate_and_price(net, spec)
+    return a.result(spec, netlist_accuracy(a.net, compiled, xte, yte))
 
 
 def logit_budget(net: ir.Netlist, frac: float) -> int:

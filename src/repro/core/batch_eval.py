@@ -536,17 +536,18 @@ def _compile_and_price(params_pop, specs, masks_serial, xte, yte, *,
     (the default objective) the accuracy is the netlist-exact simulation
     of the printed datapath instead of the float emulation (area/power
     stay on the analytic pricing, which the structural netlist cost is
-    tested to reproduce exactly). All exact netlist-mode candidates are
+    tested to reproduce exactly). Every netlist-scored candidate is
     scored in ONE packed-population launch through
     `repro.kernels.netlist_sim` — per-candidate node tables are cached
     under ``pack_key(spec)`` alongside the EvalCache keyspace, so a GA
-    revisiting genomes repacks nothing.
+    revisiting exact genomes repacks nothing.
 
     Candidates carrying approximation genes (`ModelMin.has_approx`) are
-    scored by `approx.evaluate_netlist` — the one shared policy with the
-    serial path: bit-exact simulation of the *approximated* netlist for
-    accuracy, approximation-aware structural pricing for area/power (the
-    analytic model cannot see truncated circuits).
+    always netlist-scored: `approx.approximate_and_price` — the host half
+    shared with the serial `approx.evaluate_netlist` — runs the passes and
+    the approximation-aware structural pricing per candidate (the analytic
+    model cannot see truncated circuits), and the *approximated* netlist
+    joins the same packed launch as the exact ones for its accuracy.
 
     Per-candidate fault isolation: a candidate whose compile/score raises
     (or whose accuracy comes back NaN) is retried once and then quarantined
@@ -556,16 +557,17 @@ def _compile_and_price(params_pop, specs, masks_serial, xte, yte, *,
     workers); deterministic failures burn both attempts and quarantine.
     ``ImportError`` and ``jax.errors.JaxRuntimeError`` are the process's or
     the device's faults, not the candidate's, and propagate; so does any
-    error of the packed-population launch, which every exact candidate
-    shares.
+    error of the packed-population launch, which every netlist-scored
+    candidate shares.
     """
     from repro import approx as AX               # lazy: approx imports us
     from repro import circuit as CIRC            # lazy: circuit imports us
     from repro.kernels import netlist_sim as NS  # lazy: imports circuit
 
-    full: Dict[int, MZ.EvalResult] = {}   # approx-scored or quarantined
-    compiled: Dict[int, MZ.CompiledMLP] = {}
-    nets: Dict[int, object] = {}          # netlist-exact scoring, deferred
+    full: Dict[int, MZ.EvalResult] = {}   # approximated or quarantined
+    compiled: Dict[int, MZ.CompiledMLP] = {}   # exact: analytic pricing
+    approxed: Dict[int, "AX.PricedApprox"] = {}
+    nets: Dict[int, Tuple[object, MZ.CompiledMLP]] = {}  # one launch, below
     accs: Dict[int, float] = {}
     delays: Dict[int, int] = {}
 
@@ -584,18 +586,16 @@ def _compile_and_price(params_pop, specs, masks_serial, xte, yte, *,
                 net = CIRC.compile_netlist(c)
                 stage = "score"
                 if spec.has_approx:
-                    r = AX.evaluate_netlist(net, c, spec, xte, yte)
-                    if math.isnan(float(r.accuracy)):
-                        raise FloatingPointError(
-                            "NaN accuracy out of approximated-netlist "
-                            "simulation (diverged QAT finetune?)")
-                    full[p] = r
+                    # passes and structural pricing here, accuracy from
+                    # the shared launch below
+                    approxed[p] = AX.approximate_and_price(net, spec)
+                    nets[p] = (approxed[p].net, c)
                 elif netlist:
-                    # accuracy deferred: every exact candidate joins ONE
-                    # packed-population simulation after this loop (an
-                    # integer argmax cannot come back NaN)
+                    # accuracy deferred: every netlist-scored candidate
+                    # joins ONE packed-population simulation after this
+                    # loop (an integer argmax cannot come back NaN)
                     compiled[p] = c
-                    nets[p] = net
+                    nets[p] = (net, c)
                     delays[p] = net.critical_path_levels()
                 else:
                     acc = MZ.compiled_accuracy(c, xte, yte)
@@ -625,21 +625,27 @@ def _compile_and_price(params_pop, specs, masks_serial, xte, yte, *,
                               f"{rec.message}); worst-case fitness assigned")
             full[p] = _worst_case_result(spec)
 
-    # one packed-population launch scores every deferred exact candidate;
-    # a fault here is the engine's, so it propagates
+    # one packed-population launch scores every deferred candidate, exact
+    # and approximated; a fault here is the engine's, so it propagates
     if nets:
         todo_p = sorted(nets)
-        with TR.span("netlist_sim.pack", n=len(todo_p)):
+        with TR.span("netlist_sim.pack", n=len(todo_p), approx=len(approxed)):
+            # approximated netlists are packed afresh, as the serial path
+            # packs them, and stay out of the cache: their accuracy is
+            # never read off tables packed from an earlier finetune
             packs = [_packed_netlist_for(
-                pack_key(specs[p]) if pack_key else None, nets[p], NS)
-                for p in todo_p]
+                pack_key(specs[p]) if pack_key and p in compiled else None,
+                nets[p][0], NS) for p in todo_p]
             xq = np.stack([np.asarray(
-                MZ.quantize_inputs(compiled[p], xte), np.int64)
+                MZ.quantize_inputs(nets[p][1], xte), np.int64)
                 for p in todo_p])
             pop = NS.pack_population(packs)
         pop_acc = NS.population_accuracy(pop, xq, yte)
         for j, p in enumerate(todo_p):
             accs[p] = float(pop_acc[j])
+        MT.counter("eval.approx_packed").inc(len(approxed))
+    for p, a in approxed.items():
+        full[p] = a.result(specs[p], accs[p])
 
     with TR.span("eval.price", n=len(compiled)):
         return _price(specs, compiled, full, accs, delays)
@@ -707,11 +713,10 @@ def evaluate_population(cfg: PrintedMLPConfig, specs: Sequence[ModelMin], *,
     space (old analytic cache entries keep their exact byte keys).
     ``netlist=False`` opts back out to the float emulation
     (`minimize.compiled_accuracy`). Specs with approximation genes are
-    always scored
-    on their simulated approximated netlist and priced structurally,
-    whatever ``netlist`` says; they live in the netlist keyspace (their
-    genes are part of the spec JSON, so they can never collide with an
-    exact entry).
+    always scored on their simulated approximated netlist, in the same
+    launch, and priced structurally, whatever ``netlist`` says; they live
+    in the netlist keyspace (their genes are part of the spec JSON, so they
+    can never collide with an exact entry).
 
     A candidate whose compile/score fails is retried once, then quarantined
     with worst-case fitness (never cached, so a fixed toolchain re-evaluates
@@ -875,9 +880,10 @@ def make_batch_evaluator(cfg: PrintedMLPConfig, *, epochs: int = 150,
     circuit's critical path as a third minimized objective. ``record``, if
     given, collects every EvalResult by spec json — callers (fig2, the
     example) read Pareto-front delay out of it without re-evaluating.
-    Specs carrying approximation genes are handled per candidate by
-    `evaluate_population` (simulated approximate netlist + structural
-    pricing) whatever ``netlist`` says. ``quarantine``, if given, collects
+    Specs carrying approximation genes are approximated and priced
+    structurally per candidate by `evaluate_population`, and their
+    approximated netlists simulated in the population's one launch,
+    whatever ``netlist`` says. ``quarantine``, if given, collects
     the `QuarantineRecord`s of failing specs — share the list with
     `run_nsga2(quarantine=...)` / the island runtime so quarantined specs
     surface on the final result.

@@ -1,5 +1,7 @@
 """Batched population engine: equivalence with the serial path, vectorized
 CSD pricing, and the persistent evaluation cache."""
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -283,6 +285,96 @@ def test_prefetched_bespoke_stays_on_the_host():
 
 
 # ---------------------------------------------------------------------------
+# approximated candidates in the population's one netlist launch
+# ---------------------------------------------------------------------------
+
+# WhiteWine at its published 11-4-7: accuracy is not saturated there, so a
+# candidate scored on another netlist shows. Exact and approximated
+# genomes interleaved: every approximation gene, alone and together, on
+# clustered, pruned and plain layers
+WW = dataclasses.replace(PRINTED_MLPS["whitewine"], hidden=(4,))
+APPROX_SPECS = [
+    ModelMin.uniform(2, bits=4, sparsity=0.4, clusters=8, csd_drop=1, lsb=2),
+    ModelMin.uniform(2, bits=8),
+    ModelMin.uniform(2, bits=6, csd_drop=2),
+    ModelMin.uniform(2, bits=4, sparsity=0.3, clusters=4),
+    ModelMin.uniform(2, bits=8, lsb=3, argmax_lsb=4),
+    ModelMin((LayerMin(5, 0.2, None, csd_drop=1),
+              LayerMin(4, 0.0, 3, lsb=1)), 8, argmax_lsb=2),
+    ModelMin((LayerMin(5, 0.0, 3), LayerMin(4, 0.2, None)), 8),
+]
+
+
+@pytest.fixture(scope="module")
+def approx_population():
+    """One `evaluate_population` call over the mixed population, with the
+    arguments its `_compile_and_price` got (the trained population and
+    the bespoke launch's outputs) and the counters it moved."""
+    from repro.obs import metrics as MT
+    seen = {}
+    orig = BE._compile_and_price
+
+    def capture(params_pop, specs, masks, xte, yte, **kw):
+        seen.update(args=(params_pop, specs, masks, xte, yte), kw=kw)
+        return orig(params_pop, specs, masks, xte, yte, **kw)
+
+    names = ("netlist_sim.launches", "eval.approx_packed")
+    before = {n: MT.counter(n).value for n in names}
+    BE._compile_and_price = capture
+    try:
+        rs = BE.evaluate_population(WW, APPROX_SPECS, epochs=10)
+    finally:
+        BE._compile_and_price = orig
+    moved = {n: MT.counter(n).value - before[n] for n in names}
+    return rs, seen, moved
+
+
+def test_approx_fold_matches_one_candidate_launches(approx_population):
+    """Scoring approximated candidates in the population's packed launch
+    gives each candidate what a launch of its own gives, and what the
+    serial `approx.evaluate_netlist` gives: every field equal."""
+    import jax
+    from repro import approx as AX
+    from repro import circuit as CIRC
+    rs, seen, _ = approx_population
+    params_pop, specs, masks, xte, yte = seen["args"]
+    bespoke = seen["kw"]["bespoke"]
+    assert [r.spec for r in rs] == specs == APPROX_SPECS
+    folded = BE._compile_and_price(params_pop, specs, masks, xte, yte,
+                                   bespoke=bespoke)
+    assert folded == rs
+    for p, spec in enumerate(specs):
+        def one(tree, p=p):
+            return jax.tree_util.tree_map(lambda a: a[p:p + 1], tree)
+        alone = BE._compile_and_price(one(params_pop), [spec], masks[p:p + 1],
+                                      xte, yte, bespoke=one(bespoke))
+        assert alone == [folded[p]], spec
+        if spec.has_approx:
+            c = MZ.compile_bespoke(_candidate(params_pop, p), spec, masks[p],
+                                   prefetched=_candidate(bespoke, p))
+            serial = AX.evaluate_netlist(CIRC.compile_netlist(c), c, spec,
+                                         xte, yte)
+            assert serial == folded[p], spec
+
+
+def test_approx_candidates_share_the_one_launch(approx_population):
+    """k approximated and m exact candidates: one engine launch, k counted
+    as packed; an all-exact population counts none."""
+    from repro.obs import metrics as MT
+    _, _, moved = approx_population
+    k = sum(s.has_approx for s in APPROX_SPECS)
+    assert 0 < k < len(APPROX_SPECS)
+    assert moved == {"netlist_sim.launches": 1, "eval.approx_packed": k}
+
+    launches = MT.counter("netlist_sim.launches")
+    packed = MT.counter("eval.approx_packed")
+    n0, k0 = launches.value, packed.value
+    BE.evaluate_population(WW, [s for s in APPROX_SPECS if not s.has_approx],
+                           epochs=10)
+    assert (launches.value - n0, packed.value - k0) == (1, 0)
+
+
+# ---------------------------------------------------------------------------
 # persistent cache
 # ---------------------------------------------------------------------------
 
@@ -460,3 +552,34 @@ def test_cache_skips_retraining(tmp_path, monkeypatch):
     again = BE.evaluate_population(CFG, specs, epochs=25, cache=cache)
     for a, b in zip(first, again):
         assert a.accuracy == b.accuracy and a.area_mm2 == b.area_mm2
+
+
+def test_approx_scores_read_no_earlier_pack(approx_population):
+    """An approximated candidate is scored on the netlist its own finetune
+    gave, whatever the pack cache holds from an earlier evaluation of the
+    same genome: under a different finetune (half the rows) a warm cache
+    and an empty one give it the same result."""
+    orig = BE._population_finetune
+
+    def half_rows(params0, bits, ks, masks, x, y, **kw):
+        n = x.shape[0] // 2
+        return orig(params0, bits, ks, masks, x[:n], y[:n], **kw)
+
+    BE._population_finetune = half_rows
+    try:
+        warm = BE.evaluate_population(WW, APPROX_SPECS, epochs=10)
+        saved = dict(BE._PACK_CACHE)
+        BE._PACK_CACHE.clear()
+        try:
+            cold = BE.evaluate_population(WW, APPROX_SPECS, epochs=10)
+        finally:
+            BE._PACK_CACHE.update(saved)
+    finally:
+        BE._population_finetune = orig
+    clean = approx_population[0]
+    for w, c, r in zip(warm, cold, clean):
+        if w.spec.has_approx:
+            assert w == c, w.spec
+    # the planted finetune does move the approximated answers
+    assert any(w.accuracy != r.accuracy
+               for w, r in zip(warm, clean) if w.spec.has_approx)

@@ -295,6 +295,69 @@ def test_quarantine_surfaces_in_ga_result():
     assert np.all(res.objectives[:, 1] == BE.QUARANTINE_AREA_MM2)
 
 
+# approximated candidates are scored in the population's one netlist
+# launch with the exact ones: a fault in one still quarantines it alone
+AXSPECS = [ModelMin.uniform(2, bits=5, csd_drop=1, lsb=2), QSPECS[0],
+           ModelMin.uniform(2, bits=6, sparsity=0.3, argmax_lsb=4),
+           ModelMin.uniform(2, bits=4, clusters=4, csd_drop=2)]
+
+
+@pytest.fixture(scope="module")
+def clean_approx_results():
+    return BE.evaluate_population(PRINTED_MLPS["seeds"], AXSPECS,
+                                  epochs=EPOCHS, seed=SEED)
+
+
+def _assert_only_quarantined(rs, clean, bad_index):
+    assert rs[bad_index].accuracy == 0.0
+    assert rs[bad_index].area_mm2 == BE.QUARANTINE_AREA_MM2
+    for i, r in enumerate(rs):
+        if i != bad_index:
+            assert r == clean[i], AXSPECS[i]
+
+
+@pytest.mark.parametrize("fail_attempts", [1, 2])
+def test_approx_eval_fault_isolated_in_shared_launch(fail_attempts,
+                                                     clean_approx_results):
+    bad = AXSPECS[2].to_json()
+    q = []
+    with inject_eval_faults([EvalFault(spec_json=bad,
+                                       fail_attempts=fail_attempts)]) as hook:
+        rs = BE.evaluate_population(PRINTED_MLPS["seeds"], AXSPECS,
+                                    epochs=EPOCHS, seed=SEED, quarantine=q)
+    assert hook.triggered == [(bad, a) for a in range(1, fail_attempts + 1)]
+    if fail_attempts == 1:                  # the retry absorbs it
+        assert q == []
+        assert rs == clean_approx_results
+    else:
+        assert [(r.spec_json, r.stage) for r in q] == [(bad, "compile")]
+        _assert_only_quarantined(rs, clean_approx_results, 2)
+
+
+def test_raising_approx_pass_quarantined_at_score(monkeypatch,
+                                                  clean_approx_results):
+    """A pass that raises for one candidate's genes quarantines that
+    candidate at ``score``; the approximated bystanders still share the
+    launch and come out byte-identical."""
+    from repro.approx import budget
+    bad = AXSPECS[3]
+    target = budget.ApproxParams.from_spec(bad)
+    orig = budget.build_passes
+
+    def build_passes(p):
+        if p == target:
+            raise OverflowError("injected: pass failed")
+        return orig(p)
+
+    monkeypatch.setattr(budget, "build_passes", build_passes)
+    q = []
+    rs = BE.evaluate_population(PRINTED_MLPS["seeds"], AXSPECS,
+                                epochs=EPOCHS, seed=SEED, quarantine=q)
+    assert [(r.spec_json, r.stage, r.attempts) for r in q] == \
+        [(bad.to_json(), "score", 2)]
+    _assert_only_quarantined(rs, clean_approx_results, 3)
+
+
 # ---------------------------------------------------------------------------
 # torn cache file
 # ---------------------------------------------------------------------------
