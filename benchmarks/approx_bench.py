@@ -39,15 +39,12 @@ def budget_sweep(datasets: Optional[List[str]] = None, *,
         n_layers = len(cfg.layer_dims) - 1
         spec = ModelMin.uniform(n_layers, bits=4, sparsity=0.4, clusters=8,
                                 input_bits=cfg.input_bits)
-        # the full minimization recipe (QAT finetune under the spec), same
-        # as evaluate_spec / the batched engine — the rows really are the
-        # minimized design point
-        params0, (xtr, ytr, xte, yte) = MZ.pretrain(cfg, seed=seed)
-        masks = MZ.make_masks(params0, spec)
-        params = MZ.qat_finetune(params0, spec, masks, xtr, ytr,
-                                 epochs=epochs)
-        compiled = MZ.compile_bespoke(params, spec, masks)
-        net = circuit.compile_netlist(compiled)
+        # the full minimization recipe (QAT finetune under the spec), the
+        # evaluator's at P = 1 — the rows really are the minimized design
+        # point
+        _, (_, _, xte, yte) = MZ.pretrain(cfg, seed=seed)
+        net, compiled = circuit.compile_spec(cfg, spec, epochs=epochs,
+                                             seed=seed)
         sc = circuit.structural_cost(net)
         acc0 = circuit.netlist_accuracy(net, compiled, xte, yte)
         for frac in BUDGET_FRACS:

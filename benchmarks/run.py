@@ -6,7 +6,7 @@ benches. Prints ``name,us_per_call,derived`` CSV summaries at the end.
   area_table      — paper §III baseline circuit table
   kernel_bench    — per-kernel derived TPU roofline
   roofline_table  — §Roofline across all dry-run cells
-  ga_bench        — GA hot path: serial vs batched population evaluation
+  ga_bench        — GA hot path: population evaluation, cold and warm
   circuit_bench   — bespoke netlist compile / bit-exact sim / delay
   netlist_bench   — netlist-exact vs analytic GA generation (<=2x gate)
   approx_bench    — budgeted circuit approximation + approximation-GA

@@ -100,11 +100,11 @@ def approximate_and_price(net: ir.Netlist, spec) -> PricedApprox:
 
 
 def evaluate_netlist(net: ir.Netlist, compiled, spec, xte, yte):
-    """The serial scoring of a candidate carrying approximation genes
-    (`minimize.evaluate_spec`): `approximate_and_price`, then the bit-exact
-    simulation of the approximated netlist in a launch of its own. The
-    batched path scores the same netlist in its population's one launch.
-    Returns a `minimize.EvalResult`."""
+    """One candidate carrying approximation genes, scored on its own:
+    `approximate_and_price`, then the bit-exact simulation of the
+    approximated netlist in a launch of its own. `batch_eval` scores the
+    same netlist in its population's one launch; tests hold the two
+    equal. Returns a `minimize.EvalResult`."""
     from repro.circuit.simulate import netlist_accuracy
 
     a = approximate_and_price(net, spec)

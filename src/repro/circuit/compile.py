@@ -156,10 +156,9 @@ def compile_netlist(c: "MZ.CompiledMLP") -> ir.Netlist:
 
 def compile_spec(cfg, spec, *, epochs: int = 150, seed: int = 0
                  ) -> Tuple[ir.Netlist, "MZ.CompiledMLP"]:
-    """Convenience end-to-end path: pretrain (cached) -> QAT finetune under
-    ``spec`` -> bespoke compile -> netlist."""
-    params0, (xtr, ytr, _, _) = MZ.pretrain(cfg, seed=seed)
-    masks = MZ.make_masks(params0, spec)
-    params = MZ.qat_finetune(params0, spec, masks, xtr, ytr, epochs=epochs)
-    compiled = MZ.compile_bespoke(params, spec, masks)
+    """End-to-end path for one spec: the evaluator's staging, QAT finetune
+    and bespoke compile at P = 1 (`batch_eval.compile_one`) -> netlist.
+    The `CompiledMLP` is the one `evaluate_population([spec])` scores."""
+    from repro.core import batch_eval as BE   # lazy: batch_eval imports us
+    compiled = BE.compile_one(cfg, spec, epochs=epochs, seed=seed)
     return compile_netlist(compiled), compiled

@@ -181,12 +181,11 @@ def netlist_accuracy(net: ir.Netlist, c, x: np.ndarray,
     """Netlist-exact test accuracy: ADC-quantize features with the QAT
     compile's rounding, evaluate the printed datapath, compare argmax.
 
-    Routed through the packed population engine
-    (`repro.kernels.netlist_sim`) with P=1: its executables specialize on
-    bucketed shapes shared across a dataset's candidates, so repeated
-    serial scoring (the approx budget search, `evaluate_spec`) stops
-    paying a per-netlist XLA trace+compile. Bit-exact vs `Simulator.run`
-    by the kernel's tested contract."""
+    A P = 1 launch of the population engine (`repro.kernels.netlist_sim`),
+    whose executables specialize on bucketed shapes shared across a
+    dataset's candidates, so repeated one-netlist scoring (the approx
+    budget search) pays no per-netlist XLA trace+compile. Bit-exact vs
+    `Simulator.run` by the engine's tested contract."""
     from repro.core import minimize as MZ
     from repro.kernels.netlist_sim import pack_population, population_accuracy
     xq = MZ.quantize_inputs(c, x)

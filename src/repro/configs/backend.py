@@ -1,19 +1,13 @@
 """Computation-environment configuration for multi-backend runs.
 
 One place for the process-level JAX knobs the benchmarks, the search
-runtime and the netlist-sim engines need when the repo leaves its default
+runtime and the netlist-sim engine need when the repo leaves its default
 CPU-pytest habitat: 64-bit lanes, platform selection (with the standard
 GPU XLA flag set), host-device fan-out for pmap-style CPU runs, and NaN
 debugging. All of these only take full effect at the beginning of the
 program — call :func:`configure` (or the individual setters) before any
 JAX computation, or drive them through the ``REPRO_*`` environment
 variables it reads.
-
-``default_netlist_engine`` is the routing policy for
-`repro.kernels.netlist_sim`: the wave-scheduled ``lax.scan`` engine on
-every backend. The Pallas netlist kernel does not compile for TPU yet (its
-``(1, N)`` table blocks are not (8, 128)-aligned), so it runs only when
-asked for, in interpret mode off-TPU. ``REPRO_NETLIST_ENGINE`` overrides.
 
 ``enable_compile_cache`` places JAX's persistent compilation cache for the
 entry points (examples, benchmarks, ``chip_smoke.py``); importing ``repro``
@@ -31,7 +25,7 @@ import jax
 
 def jax_enable_x64(use_x64: bool) -> None:
     """Default integer/float width 64 bits process-wide. The netlist-sim
-    engines prefer the *local* ``jax.enable_x64(True)`` scope and only need
+    engine prefers the *local* ``jax.enable_x64(True)`` scope and only needs
     this for debugging sessions."""
     if not use_x64:
         use_x64 = bool(os.getenv("JAX_ENABLE_X64", 0))
@@ -71,15 +65,6 @@ def set_cpu_cores(n: int) -> None:
 def set_debug_nan(flag: bool) -> None:
     """Raise on the first NaN any jitted computation produces."""
     jax.config.update("jax_debug_nans", flag)
-
-
-def default_netlist_engine() -> str:
-    """'levels' on every backend; overridable with
-    ``REPRO_NETLIST_ENGINE=levels|pallas|ref``."""
-    env = os.environ.get("REPRO_NETLIST_ENGINE", "").strip().lower()
-    if env in ("levels", "pallas", "ref"):
-        return env
-    return "levels"
 
 
 # <repo root>/.jax_cache: a fixed path, because the path is part of the

@@ -1,19 +1,19 @@
-"""Batched population evaluation — the GA's hot path, one jit instead of N.
+"""Population evaluation: the one evaluation path, for the GA and for
+single specs (`minimize.evaluate_spec` is its P = 1 case).
 
-`run_nsga2` spends essentially all of its time in per-candidate QAT
-finetuning: the serial path traces and compiles a fresh `jax.jit` train
-loop for every spec. This module stacks a whole population's genomes into
-padded per-layer arrays (bits, cluster counts, pruning masks), and runs the
-QAT finetune for all candidates in a single `jax.vmap`-over-`lax.scan`
-jitted call against the shared pretrained weights. Compiled circuits are
-then priced for the whole population at once through the vectorized
-`hw_model.mlp_cost_batch`.
+A population's genomes are stacked into padded per-layer arrays (bits,
+cluster counts, pruning masks) and QAT-finetuned in a single
+`jax.vmap`-over-`lax.scan` jitted call against the shared pretrained
+weights (`_population_finetune`); one more launch computes every
+candidate's codebooks and quantizer (`_population_bespoke`), and one copy
+brings both to the host. Each candidate is then compiled to its bespoke
+netlist, all are scored in one `repro.kernels.netlist_sim` launch, and
+the population is priced at once through `hw_model.mlp_cost_batch`.
 
-The dynamic (traced) spec transforms are written to match the serial
-static-spec path operation-for-operation:
+The spec transforms take traced genes:
 
-* quantization: integer `qmax` built by bit-shift (no float pow), same
-  scale/round/clip sequence as `quantization.fake_quant`;
+* quantization: symmetric per-tensor, integer `qmax` built by bit-shift so
+  the grid equals `quantization.quantize_int`'s static one;
 * clustering: padded Lloyd k-means over `K_MAX` slots with invalid slots
   masked to +inf distance — identical quantile init, identical argmin
   tie-breaking, so valid-slot centroids equal `clustering._kmeans_1d`'s;
@@ -258,8 +258,8 @@ def _population_bespoke(trained, bits, ks, masks, *, k_max: int = K_MAX):
     Returns per layer a dict of (P, ...) arrays: "cb" (d_in, k_max) float32
     and "idx" (d_in, d_out) int32, the per-input k-means the finetune
     trains against (valid slots < k); "q" (d_in, d_out) int32 and "scale"
-    float32, the symmetric per-tensor quantizer at bits (0 -> 8, the serial
-    default). Both are computed for every candidate; the host snap
+    float32, the symmetric per-tensor quantizer at bits (0 -> 8, the
+    default width). Both are computed for every candidate; the host snap
     (`minimize._snap_bespoke`) reads the one its spec asks for.
     """
     def one(layers, bits_row, ks_row, masks_row):
@@ -282,9 +282,10 @@ def _bespoke_k_max(ks: np.ndarray) -> int:
 
 
 def bespoke_one(params, spec: ModelMin, masks):
-    """`_population_bespoke` at P = 1, the serial `minimize.compile_bespoke`'s
-    device half: -> the candidate's outputs on the host. ``masks``: per
-    layer a mask or None, as `minimize.make_masks` gives them."""
+    """`_population_bespoke` at P = 1, `minimize.compile_bespoke`'s device
+    half when no launch ran for the candidate: -> its outputs on the host.
+    ``masks``: per layer a mask or None, as `minimize.make_masks` gives
+    them."""
     bits, ks = stack_specs([spec])
     one = jax.tree_util.tree_map(lambda a: np.asarray(a, np.float32)[None],
                                  params)
@@ -345,7 +346,7 @@ class EvalCache:
     netlist-exact evaluations — a different objective, never mixed with
     analytic entries; approximated specs carry their genes in the spec
     JSON and always live in the netlist keyspace) so resumed searches,
-    repeated sweeps and the serial/batched paths all share results.
+    repeated sweeps and single-spec evaluations all share results.
     ``flush`` re-reads and merges the on-disk file first, so concurrent
     sweep processes sharing a cache file union their entries instead of
     clobbering each other.
@@ -630,9 +631,10 @@ def _compile_and_price(params_pop, specs, masks_serial, xte, yte, *,
     if nets:
         todo_p = sorted(nets)
         with TR.span("netlist_sim.pack", n=len(todo_p), approx=len(approxed)):
-            # approximated netlists are packed afresh, as the serial path
-            # packs them, and stay out of the cache: their accuracy is
-            # never read off tables packed from an earlier finetune
+            # approximated netlists are packed afresh, as
+            # `approx.evaluate_netlist` packs them, and stay out of the
+            # cache: their accuracy is never read off tables packed from
+            # an earlier finetune
             packs = [_packed_netlist_for(
                 pack_key(specs[p]) if pack_key and p in compiled else None,
                 nets[p][0], NS) for p in todo_p]
@@ -696,6 +698,83 @@ def _price(specs, compiled: Dict[int, MZ.CompiledMLP],
             for p, spec in enumerate(specs)]
 
 
+def _train_population(cfg: PrintedMLPConfig, specs: List[ModelMin], *,
+                      epochs: int, seed: int):
+    """Stage, QAT-finetune and bespoke-launch ``specs`` (distinct) on the
+    device, then fetch both in one copy.
+
+    -> (trained, bespoke, masks, (xte, yte)): the trained params and the
+    `_population_bespoke` outputs as host arrays with a leading axis of
+    ``len(specs)``, each spec's per-layer masks (`stack_masks`' serial
+    layout) and the test split. Counts ``eval.specs_evaluated`` once per
+    spec, as `minimize.compile_bespoke` counts ``eval.bespoke_prefetched``
+    once per compile from these outputs. Spans: ``eval.prepare``
+    (pretrain memo, `stack_specs`, `stack_masks`, bucket padding,
+    uploads), ``eval.finetune`` (under tracing) and ``eval.fetch``.
+    """
+    n_real = len(specs)
+    MT.counter("eval.specs_evaluated").inc(n_real)
+    with TR.span("eval.prepare"):
+        # pad to a power-of-two bucket by repeating the last spec: the
+        # jit specializes on the population axis, and a GA's uncached
+        # count differs almost every generation — bucketing keeps one
+        # compiled executable per bucket instead of a retrace per
+        # generation
+        bucket = 1 << (n_real - 1).bit_length()
+        padded = specs + [specs[-1]] * (bucket - n_real)
+        params0, (xtr, ytr, xte, yte) = MZ.pretrain(cfg, seed=seed)
+        bits, ks = stack_specs(padded)
+        stacked, masks_serial = stack_masks(params0, padded)
+        masks = tuple(jnp.asarray(m) for m in stacked)
+        # population-bucket padding accounting (same convention as
+        # netlist_sim's lane padding): real specs vs padded bucket slots
+        MT.counter("eval.pad.specs_real").inc(n_real)
+        MT.counter("eval.pad.specs_total").inc(bucket)
+        bits_j, ks_j = jnp.asarray(bits), jnp.asarray(ks)
+        xtr_j, ytr_j = jnp.asarray(xtr), jnp.asarray(ytr)
+        args = (params0, bits_j, ks_j, masks, xtr_j, ytr_j)
+        kw = dict(epochs=epochs, lr=2e-3)
+
+    # the dispatch wrapper times DISPATCH of the population jit (never
+    # runs inside traced code); the first call per (dataset, bucket,
+    # epochs) pays XLA compilation, is tagged `first` so reports split
+    # compile_ms out, and has its cost/memory analysis captured into
+    # the executable registry
+    if not TR.active():
+        trained = _population_finetune(*args, **kw)
+    else:
+        TR.event("eval.padding", dataset=cfg.name, specs_real=n_real,
+                 specs_total=bucket)
+        key = ("finetune", cfg.name, bucket, epochs, tuple(cfg.layer_dims))
+        with PF.dispatch("eval.finetune", key,
+                         lower=lambda: _population_finetune.lower(
+                             *args, **kw),
+                         dataset=cfg.name, bucket=bucket, n=n_real):
+            trained = _population_finetune(*args, **kw)
+            jax.block_until_ready(trained)
+    with TR.span("eval.fetch", n=n_real):
+        # the bespoke compile's device half for the whole population,
+        # queued behind the finetune before anything syncs, then ONE
+        # device-to-host copy of both; the bucket padding is cut on the
+        # host
+        bespoke = _population_bespoke(trained, bits_j, ks_j, masks,
+                                      k_max=_bespoke_k_max(ks))
+        trained, bespoke = jax.tree_util.tree_map(
+            lambda a: a[:n_real], jax.device_get((trained, bespoke)))
+    return trained, bespoke, masks_serial[:n_real], (xte, yte)
+
+
+def compile_one(cfg: PrintedMLPConfig, spec: ModelMin, *, epochs: int = 150,
+                seed: int = 0) -> MZ.CompiledMLP:
+    """The evaluator's compile at P = 1: `_train_population` on ``spec``,
+    then `minimize.compile_bespoke` on its fetched outputs — the
+    `CompiledMLP` `evaluate_population([spec])` scores."""
+    trained, bespoke, masks, _ = _train_population(cfg, [spec],
+                                                   epochs=epochs, seed=seed)
+    one = jax.tree_util.tree_map(lambda a: a[0], (trained, bespoke))
+    return MZ.compile_bespoke(one[0], spec, masks[0], prefetched=one[1])
+
+
 def evaluate_population(cfg: PrintedMLPConfig, specs: Sequence[ModelMin], *,
                         epochs: int = 150, seed: int = 0,
                         cache: Optional[EvalCache] = None,
@@ -704,7 +783,7 @@ def evaluate_population(cfg: PrintedMLPConfig, specs: Sequence[ModelMin], *,
                         ) -> List[MZ.EvalResult]:
     """Evaluate a population of specs with ONE vmapped QAT finetune + ONE
     vectorized pricing pass. Order-preserving; duplicates and cache hits
-    are evaluated once. Drop-in for `[evaluate_spec(cfg, s) for s in specs]`.
+    are evaluated once; `minimize.evaluate_spec` is the call at P = 1.
 
     The accuracy objective defaults to the bit-exact simulation of each
     candidate's compiled netlist (`repro.circuit`) — the printed datapath
@@ -738,106 +817,54 @@ def _evaluate_unique(cfg: PrintedMLPConfig, specs: List[ModelMin], *,
                      quarantine: Optional[List[QuarantineRecord]]
                      ) -> Dict[str, MZ.EvalResult]:
     """`evaluate_population`'s body: spec json -> result, each distinct
-    spec once. One span per stage: ``eval.prepare`` (lookup, staging,
-    uploads), ``eval.finetune``, ``eval.fetch`` (the bespoke launch's
-    dispatch and the one device-to-host copy), ``eval.compile_price``."""
-    with TR.span("eval.prepare"):
-        from repro.verify.diagnostics import verify_enabled
-        if specs and verify_enabled():
-            # static spec lint before any costly QAT: gene-range/arch
-            # legality + serialize->parse->serialize byte-stability (a
-            # non-round-tripping spec would fracture the cache keyspace)
-            from repro.verify.spec import check_specs
-            check_specs(specs, cfg)
-        results: Dict[str, MZ.EvalResult] = {}
-        todo: List[ModelMin] = []
-        queued = set()
-        n_hits = 0
-        for s in specs:
-            k = s.to_json()
-            if k in results or k in queued:
-                continue
-            hit = (cache.get(cfg.name, seed, epochs, s,
-                             netlist=netlist or s.has_approx)
-                   if cache else None)
-            n_hits += hit is not None
-            if hit is not None and hit.delay_levels is not None:
-                # entries from caches predating the circuit compiler carry
-                # no delay — fall through and re-evaluate so they upgrade
-                # in place
-                results[k] = hit
-            else:
-                todo.append(s)
-                queued.add(k)
+    spec once: the cache lookup, then `_train_population` on the specs
+    left to evaluate and ``eval.compile_price`` on what it fetched."""
+    from repro.verify.diagnostics import verify_enabled
+    if specs and verify_enabled():
+        # static spec lint before any costly QAT: gene-range/arch
+        # legality + serialize->parse->serialize byte-stability (a
+        # non-round-tripping spec would fracture the cache keyspace)
+        from repro.verify.spec import check_specs
+        check_specs(specs, cfg)
+    results: Dict[str, MZ.EvalResult] = {}
+    todo: List[ModelMin] = []
+    queued = set()
+    n_hits = 0
+    for s in specs:
+        k = s.to_json()
+        if k in results or k in queued:
+            continue
+        hit = (cache.get(cfg.name, seed, epochs, s,
+                         netlist=netlist or s.has_approx)
+               if cache else None)
+        n_hits += hit is not None
+        if hit is not None and hit.delay_levels is not None:
+            # entries from caches predating the circuit compiler carry
+            # no delay — fall through and re-evaluate so they upgrade
+            # in place
+            results[k] = hit
+        else:
+            todo.append(s)
+            queued.add(k)
 
-        MT.counter("eval.specs_requested").inc(len(specs))
-        MT.counter("eval.specs_cached").inc(n_hits)
-        MT.counter("eval.specs_evaluated").inc(len(todo))
-        TR.event("eval.batch", dataset=cfg.name, requested=len(specs),
-                 hits=n_hits, evaluated=len(todo))
-        if todo:
-            n_real = len(todo)
-            # pad to a power-of-two bucket by repeating the last spec: the
-            # jit specializes on the population axis, and a GA's uncached
-            # count differs almost every generation — bucketing keeps one
-            # compiled executable per bucket instead of a retrace per
-            # generation
-            bucket = 1 << (n_real - 1).bit_length()
-            padded = todo + [todo[-1]] * (bucket - n_real)
-            params0, (xtr, ytr, xte, yte) = MZ.pretrain(cfg, seed=seed)
-            bits, ks = stack_specs(padded)
-            stacked, masks_serial = stack_masks(params0, padded)
-            masks = tuple(jnp.asarray(m) for m in stacked)
-            # population-bucket padding accounting (same convention as
-            # netlist_sim's lane padding): real specs vs padded bucket slots
-            MT.counter("eval.pad.specs_real").inc(n_real)
-            MT.counter("eval.pad.specs_total").inc(bucket)
-            bits_j, ks_j = jnp.asarray(bits), jnp.asarray(ks)
-            xtr_j, ytr_j = jnp.asarray(xtr), jnp.asarray(ytr)
-            args = (params0, bits_j, ks_j, masks, xtr_j, ytr_j)
-            kw = dict(epochs=epochs, lr=2e-3)
+    MT.counter("eval.specs_requested").inc(len(specs))
+    MT.counter("eval.specs_cached").inc(n_hits)
+    TR.event("eval.batch", dataset=cfg.name, requested=len(specs),
+             hits=n_hits, evaluated=len(todo))
 
     if todo:
-        # the dispatch wrapper times DISPATCH of the population jit (never
-        # runs inside traced code); the first call per (dataset, bucket,
-        # epochs) pays XLA compilation, is tagged `first` so reports split
-        # compile_ms out, and has its cost/memory analysis captured into
-        # the executable registry
-        if not TR.active():
-            trained = _population_finetune(*args, **kw)
-        else:
-            TR.event("eval.padding", dataset=cfg.name, specs_real=n_real,
-                     specs_total=bucket)
-            key = ("finetune", cfg.name, bucket, epochs,
-                   tuple(cfg.layer_dims))
-            with PF.dispatch("eval.finetune", key,
-                             lower=lambda: _population_finetune.lower(
-                                 *args, **kw),
-                             dataset=cfg.name, bucket=bucket, n=n_real):
-                trained = _population_finetune(*args, **kw)
-                jax.block_until_ready(trained)
-        with TR.span("eval.fetch", n=n_real):
-            # the bespoke compile's device half for the whole population,
-            # queued behind the finetune before anything syncs, then ONE
-            # device-to-host copy of both; the bucket padding is cut on
-            # the host
-            bespoke = _population_bespoke(trained, bits_j, ks_j, masks,
-                                          k_max=_bespoke_k_max(ks))
-            trained, bespoke = jax.tree_util.tree_map(
-                lambda a: a[:n_real], jax.device_get((trained, bespoke)))
+        trained, bespoke, masks, (xte, yte) = _train_population(
+            cfg, todo, epochs=epochs, seed=seed)
         recs: List[QuarantineRecord] = []
 
         def pack_key(s: ModelMin) -> str:
             return EvalCache.key(cfg.name, seed, epochs, s,
                                  netlist=True) + "|pack"
 
-        with TR.span("eval.compile_price", dataset=cfg.name, n=n_real):
-            priced = _compile_and_price(trained, todo,
-                                        masks_serial[:n_real],
-                                        xte, yte, bespoke=bespoke,
-                                        netlist=netlist,
-                                        quarantine=recs,
-                                        pack_key=pack_key)
+        with TR.span("eval.compile_price", dataset=cfg.name, n=len(todo)):
+            priced = _compile_and_price(trained, todo, masks, xte, yte,
+                                        bespoke=bespoke, netlist=netlist,
+                                        quarantine=recs, pack_key=pack_key)
         for r in priced:
             results[r.spec.to_json()] = r
             if cache is not None and \

@@ -8,24 +8,26 @@ Flow per candidate spec (bits/sparsity/clusters per layer):
     -> bespoke "compile": integer weights + shared-product codebooks
     -> test accuracy of the compiled arithmetic + printed area (hw_model)
 
-The standalone sweeps reproduce Fig. 1; `core.ga` drives the combined search
-of Fig. 2 through `evaluate_spec`.
+This module holds the training loop, the pretrain, the host half of the
+bespoke compile and the compiled arithmetic. The finetune and the bespoke
+compile's device half run for whole populations in `core.batch_eval`;
+`evaluate_spec` and the Fig. 1 sweeps are calls of its
+`evaluate_population`, and `core.ga` drives the combined search of
+Fig. 2 through it.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.printed_mlp import PrintedMLPConfig
-from repro.core import clustering as C
 from repro.core import hw_model as HW
 from repro.core import pruning as P
-from repro.core import quantization as Q
 from repro.core.compression_spec import LayerMin, ModelMin
 from repro.data.uci import dataset_for
 from repro.nn import mlp as M
@@ -84,31 +86,6 @@ def pretrain(cfg: PrintedMLPConfig, *, epochs: int = 600, lr: float = 5e-3,
         _train, epochs=epochs, lr=lr, w_transform=lambda i, w: w))
     params = fit(params, jnp.asarray(xtr), jnp.asarray(ytr))
     return params, (xtr, ytr, xte, yte)
-
-
-# ---------------------------------------------------------------------------
-# QAT finetune under a spec
-# ---------------------------------------------------------------------------
-
-
-def _qat_transform(spec: ModelMin, masks):
-    def t(i, w):
-        lm = spec.layers[i]
-        if masks[i] is not None:
-            w = P.apply_mask(w, masks[i])
-        if lm.clusters is not None:
-            w = C.cluster_ste(w, lm.clusters, per_input=True)
-        if lm.bits is not None:
-            w = Q.fake_quant(w, Q.QuantConfig(bits=lm.bits))
-        return w
-    return t
-
-
-def qat_finetune(params0, spec: ModelMin, masks, x, y, *, epochs: int = 150,
-                 lr: float = 2e-3):
-    fit = jax.jit(functools.partial(
-        _train, epochs=epochs, lr=lr, w_transform=_qat_transform(spec, masks)))
-    return fit(params0, jnp.asarray(x), jnp.asarray(y))
 
 
 # ---------------------------------------------------------------------------
@@ -276,43 +253,16 @@ def make_masks(params0, spec: ModelMin):
 def evaluate_spec(cfg: PrintedMLPConfig, spec: ModelMin, *,
                   epochs: int = 150, seed: int = 0,
                   netlist: bool = True) -> EvalResult:
-    """Serial single-spec evaluation, objective-identical to
-    `batch_eval.evaluate_population`: accuracy defaults to the bit-exact
-    simulation of the compiled netlist (the printed datapath); pass
-    ``netlist=False`` for the analytic float-emulation opt-out.
-    Area/power stay on the analytic pricing either way."""
-    params0, (xtr, ytr, xte, yte) = pretrain(cfg, seed=seed)
-    masks = make_masks(params0, spec)
-    params = qat_finetune(params0, spec, masks, xtr, ytr, epochs=epochs)
-    compiled = compile_bespoke(params, spec, masks)
-    from repro.circuit import compile as CC     # lazy: circuit imports us
-    net = CC.compile_netlist(compiled)
-    if spec.has_approx:
-        # the printed circuit is the approximated netlist — one shared
-        # scoring policy with the batched path (`approx.evaluate_netlist`)
-        from repro import approx as AX
-        return AX.evaluate_netlist(net, compiled, spec, xte, yte)
-    if netlist:
-        from repro import circuit as CIRC
-        acc = CIRC.netlist_accuracy(net, compiled, xte, yte)
-    else:
-        acc = compiled_accuracy(compiled, xte, yte)
-    cost = compiled_cost(compiled)
-    return EvalResult(spec, acc, cost.area_mm2, cost.power_mw,
-                      cost.n_multipliers,
-                      delay_levels=net.critical_path_levels())
-
-
-def evaluate_specs(cfg: PrintedMLPConfig, specs: Sequence[ModelMin], *,
-                   epochs: int = 150, seed: int = 0,
-                   cache=None) -> List[EvalResult]:
-    """Batched counterpart of `evaluate_spec`: the whole list is QAT-
-    finetuned in one vmapped jit and priced in one vectorized hw_model
-    call (see `core.batch_eval`). `cache` is an optional
-    `batch_eval.EvalCache` for cross-run persistence."""
-    from repro.core import batch_eval as BE      # lazy: avoids import cycle
-    return BE.evaluate_population(cfg, specs, epochs=epochs, seed=seed,
-                                  cache=cache)
+    """One spec through the population path:
+    `batch_eval.evaluate_population(cfg, [spec], ...)[0]`. Accuracy
+    defaults to the bit-exact simulation of the compiled netlist (the
+    printed datapath); ``netlist=False`` is the analytic float-emulation
+    opt-out, and approximated specs are always scored on their
+    approximated netlist. A spec whose evaluation fails is retried once,
+    then quarantined with worst-case fitness and a warning."""
+    from repro.core import batch_eval as BE      # lazy: imports us
+    return BE.evaluate_population(cfg, [spec], epochs=epochs, seed=seed,
+                                  netlist=netlist)[0]
 
 
 def baseline(cfg: PrintedMLPConfig, *, seed: int = 0) -> EvalResult:
@@ -325,21 +275,25 @@ def baseline(cfg: PrintedMLPConfig, *, seed: int = 0) -> EvalResult:
 def quant_sweep(cfg, bits_range=None, *, epochs=150, seed=0):
     if bits_range is None:
         bits_range = range(2, 8)
+    from repro.core import batch_eval as BE
     n = len(cfg.layer_dims) - 1
-    return [evaluate_spec(cfg, ModelMin.uniform(n, bits=b), epochs=epochs,
-                          seed=seed) for b in bits_range]
+    return BE.evaluate_population(
+        cfg, [ModelMin.uniform(n, bits=b) for b in bits_range],
+        epochs=epochs, seed=seed)
 
 
 def prune_sweep(cfg, sparsities=(0.2, 0.3, 0.4, 0.5, 0.6), *, epochs=150,
                 seed=0):
+    from repro.core import batch_eval as BE
     n = len(cfg.layer_dims) - 1
-    return [evaluate_spec(
-        cfg, ModelMin.uniform(n, bits=8, sparsity=s), epochs=epochs,
-        seed=seed) for s in sparsities]
+    return BE.evaluate_population(
+        cfg, [ModelMin.uniform(n, bits=8, sparsity=s) for s in sparsities],
+        epochs=epochs, seed=seed)
 
 
 def cluster_sweep(cfg, ks=(2, 3, 4, 6, 8), *, epochs=150, seed=0):
+    from repro.core import batch_eval as BE
     n = len(cfg.layer_dims) - 1
-    return [evaluate_spec(
-        cfg, ModelMin.uniform(n, bits=8, clusters=k), epochs=epochs,
-        seed=seed) for k in ks]
+    return BE.evaluate_population(
+        cfg, [ModelMin.uniform(n, bits=8, clusters=k) for k in ks],
+        epochs=epochs, seed=seed)

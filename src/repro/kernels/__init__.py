@@ -14,12 +14,13 @@ adapted to the TPU memory hierarchy (DESIGN.md §3):
   ssm_scan            -- Mamba-1 selective scan with the time loop inside the
                          kernel and the recurrent state in VMEM scratch (the
                          TPU-native analogue of the CUDA selective_scan)
-  netlist_sim         -- population-batched printed-netlist simulation: dense
-                         packed node tables, grid over candidates x input
-                         tiles, levels as an unrolled scan (the engine behind
-                         the default netlist-exact GA objective)
 
 Each kernel ships kernel.py (pl.pallas_call + BlockSpec), ops.py (jit'd
 wrapper; interpret=True on CPU) and ref.py (oracle); tests sweep
 shapes/dtypes and assert bit-exactness / allclose against the oracle.
+
+netlist_sim, the engine behind the default netlist-exact GA objective, is
+no Pallas kernel: population-batched printed-netlist simulation as one
+jitted `lax.scan` over a host-built wave schedule (ops.py), with its
+packing (pack.py) and NumPy oracle (ref.py).
 """

@@ -1,5 +1,5 @@
-"""Population-batched netlist simulation (see ops.py for the engine map)."""
-from repro.kernels.netlist_sim.kernel import netlist_sim_pallas  # noqa: F401
+"""Population-batched netlist simulation: one `levels` launch scores P
+packed candidates (ops.py), with its NumPy oracle (ref.py)."""
 from repro.kernels.netlist_sim.ops import (population_accuracy,  # noqa: F401
                                            simulate_population)
 from repro.kernels.netlist_sim.pack import (NOP,  # noqa: F401

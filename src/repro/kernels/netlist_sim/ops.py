@@ -1,51 +1,39 @@
 """Population netlist simulation: one launch for P candidates x B samples.
 
-Why this exists (the repo's slowest path, measured): per-candidate
-`circuit.simulate.Simulator` builds a fresh jitted executable per netlist —
-~1-2 s of trace+compile each against ~ms of actual integer compute, so a
-16-candidate GA generation under ``netlist=True`` was ~25 s of pure XLA
-compilation. Here the *whole population* runs through one shape-stable
-executable; shapes are bucketed to powers of two so GA generations reuse
-executables instead of retracing.
+The whole population runs through one shape-stable executable, where a
+per-netlist jit (`circuit.simulate.Simulator`) would trace and compile
+once per candidate. Shapes are bucketed to powers of two so GA generations
+reuse executables instead of retracing.
 
-Two engines, one packing, one oracle:
-
-* ``"levels"`` (the default on every backend) — a host-built global wave
-  schedule over the concatenated node tables, executed as ONE ``lax.scan``
-  over fixed (window,)-wide waves with branchless opcode dispatch. Each
-  global topological level is chunked into ceil(count/window) waves; every
-  wave of level l-1 precedes every wave of level l, so intra-wave
-  independence is inherited from the level structure. Padding lanes carry
-  ``op = NOP`` and scatter to a dummy slot.
-* ``"pallas"`` (explicit ``engine="pallas"`` only, int32-width
-  populations) — the bespoke kernel in `kernel.py`: grid over candidates x
-  input tiles, levels unrolled inside the kernel. Runs interpret=True
-  off-TPU like the other five kernels. The TPU compiler refuses it: its
-  ``(1, N)`` table blocks are not (8, 128)-aligned.
+The engine, ``levels``: a host-built global wave schedule over the
+concatenated node tables, executed as ONE ``lax.scan`` over fixed
+(window,)-wide waves with branchless opcode dispatch. Each global
+topological level is chunked into ceil(count/window) waves; every wave of
+level l-1 precedes every wave of level l, so intra-wave independence is
+inherited from the level structure. Padding lanes carry ``op = NOP`` and
+scatter to a dummy slot.
 
 Lane width is the verifier's per-node bound maximized over the population:
 int32 when every word fits 32 bits, else int64 under a local
 ``jax.enable_x64(True)`` scope (`repro.verify.netlist.fits_int32`
-semantics). Both engines are bit-exact against `circuit.simulate.simulate`
-and the NumPy oracle in `ref.py` — tested on all four datasets.
+semantics). The engine is bit-exact against `circuit.simulate.simulate`
+and the NumPy oracle in `ref.py` (`simulate_population_ref`), tested on
+all four datasets.
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
-import functools
-from typing import Dict, Optional, Tuple
+from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.circuit import ir
-from repro.kernels.netlist_sim.kernel import netlist_sim_pallas
 from repro.kernels.netlist_sim.pack import (NOP, PackedPopulation,
                                             pack_netlist, pack_population)
-from repro.kernels.netlist_sim.ref import (_normalize_x,
-                                           simulate_population_ref)
+from repro.kernels.netlist_sim.ref import _normalize_x
 from repro.obs import metrics as MT
 from repro.obs import prof as PF
 from repro.obs import trace as TR
@@ -193,9 +181,8 @@ def _real_ops(pop: PackedPopulation) -> int:
     return int((valid & (pop.op >= _SHL) & (pop.op != _ARGMAX)).sum())
 
 
-def _run_engine(pop: PackedPopulation, x: np.ndarray, engine: str,
-                window: int, block_b: int,
-                interpret: Optional[bool]) -> Tuple[np.ndarray, Dict]:
+def _run_engine(pop: PackedPopulation, x: np.ndarray, window: int,
+                block_b: int) -> Tuple[np.ndarray, Dict]:
     """-> (amx, stats): amx (P, B, C) int64 for the real (unpadded)
     candidates; stats the launch's padding/executable accounting —
     ``key`` is the *exact* static-shape specialization tuple of the jit
@@ -208,147 +195,84 @@ def _run_engine(pop: PackedPopulation, x: np.ndarray, engine: str,
     dtype = jnp.int32 if fits32 else jnp.int64
     lane = "int32" if fits32 else "int64"
 
-    if engine == "pallas":
-        if not fits32:
-            # TPU Pallas has no int64 lanes — wide populations take the
-            # levels engine whatever the caller asked for
-            engine = "levels"
-        elif interpret is None:
-            interpret = jax.default_backend() != "tpu"
+    p_pad = _bucket(P)
+    ppad, xpad = _pad_candidates(pop, x, p_pad)
+    sched = _global_schedule(ppad, window)
+    bt = min(_bucket(B), block_b)
+    n_tiles = -(-B // bt)
+    nw, W = sched.OP.shape
+    n_buf = sched.vals0.size
+    n_in, C = sched.inp_cols.shape[1], sched.am_cols.shape[1]
+    stats = {
+        "key": ("netlist_levels", nw, W, n_buf, p_pad, n_in, C, bt, lane),
+        "cand_real": P, "cand_total": p_pad,
+        # wave lanes actually carrying an op (incl. the repeated padding
+        # candidates) vs the bucketed wave grid
+        "lanes_used": int((sched.OP != NOP).sum()),
+        "lanes_total": nw * W,
+        "ops_real": _real_ops(pop),
+        "rows_real": B, "rows_total": n_tiles * bt,
+        "tiles": n_tiles,
+    }
 
-    if engine == "levels":
-        p_pad = _bucket(P)
-        ppad, xpad = _pad_candidates(pop, x, p_pad)
-        sched = _global_schedule(ppad, window)
-        bt = min(_bucket(B), block_b)
-        n_tiles = -(-B // bt)
-        nw, W = sched.OP.shape
-        n_buf = sched.vals0.size
-        n_in, C = sched.inp_cols.shape[1], sched.am_cols.shape[1]
-        stats = {
-            "engine": "levels",
-            "key": ("netlist_levels", nw, W, n_buf, p_pad, n_in, C, bt,
-                    lane),
-            "cand_real": P, "cand_total": p_pad,
-            # wave lanes actually carrying an op (incl. the repeated
-            # padding candidates) vs the bucketed wave grid
-            "lanes_used": int((sched.OP != NOP).sum()),
-            "lanes_total": nw * W,
-            "ops_real": _real_ops(pop),
-            "rows_real": B, "rows_total": n_tiles * bt,
-            "tiles": n_tiles,
-        }
+    def _lower():
+        tile = xc[0:bt]
+        pad = bt - tile.shape[0]
+        if pad:
+            tile = np.concatenate([tile, tile[-1:].repeat(pad, 0)])
+        with (contextlib.nullcontext() if fits32
+              else jax.enable_x64(True)):
+            return _run_levels.lower(*args, vals0, inp_cols, am_cols,
+                                     jnp.asarray(tile.astype(dtype)))
 
-        def _lower():
-            tile = xc[0:bt]
+    outs = []
+    ctx = (PF.dispatch("kernels.netlist_sim.levels", stats["key"],
+                       lower=_lower, p=P, b=B, tiles=n_tiles)
+           if TR.active() else contextlib.nullcontext())
+    with ctx, scope:
+        args = [jnp.asarray(a) for a in
+                (sched.OP, sched.AI, sched.BI, sched.SH, sched.OUT)]
+        vals0 = jnp.asarray(sched.vals0.astype(dtype))
+        inp_cols = jnp.asarray(sched.inp_cols)
+        am_cols = jnp.asarray(sched.am_cols)
+        # (P, B, n_in) -> (B, P*n_in) columns in global-position order
+        xc = np.ascontiguousarray(xpad.transpose(1, 0, 2).reshape(B, -1))
+        for b0 in range(0, B, bt):
+            tile = xc[b0:b0 + bt]
             pad = bt - tile.shape[0]
             if pad:
                 tile = np.concatenate([tile, tile[-1:].repeat(pad, 0)])
-            with (contextlib.nullcontext() if fits32
-                  else jax.enable_x64(True)):
-                return _run_levels.lower(*args, vals0, inp_cols, am_cols,
-                                         jnp.asarray(tile.astype(dtype)))
-
-        outs = []
-        ctx = (PF.dispatch("kernels.netlist_sim.levels", stats["key"],
-                           lower=_lower, p=P, b=B, tiles=n_tiles)
-               if TR.active() else contextlib.nullcontext())
-        with ctx, scope:
-            args = [jnp.asarray(a) for a in
-                    (sched.OP, sched.AI, sched.BI, sched.SH, sched.OUT)]
-            vals0 = jnp.asarray(sched.vals0.astype(dtype))
-            inp_cols = jnp.asarray(sched.inp_cols)
-            am_cols = jnp.asarray(sched.am_cols)
-            # (P, B, n_in) -> (B, P*n_in) columns in global-position order
-            xc = np.ascontiguousarray(
-                xpad.transpose(1, 0, 2).reshape(B, -1))
-            for b0 in range(0, B, bt):
-                tile = xc[b0:b0 + bt]
-                pad = bt - tile.shape[0]
-                if pad:
-                    tile = np.concatenate([tile, tile[-1:].repeat(pad, 0)])
-                amx = _run_levels(*args, vals0, inp_cols, am_cols,
-                                  jnp.asarray(tile.astype(dtype)))
-                outs.append(np.asarray(amx[:bt - pad], np.int64))
-        amx = np.concatenate(outs).transpose(1, 0, 2)     # (P_pad, B, C)
-        return amx[:P], stats
-
-    if engine == "pallas":
-        bt = min(_bucket(B), 256)
-        bpad = -B % bt
-        xp = (np.concatenate([x, x[:, -1:].repeat(bpad, 1)], axis=1)
-              if bpad else x)
-        N, Lp1 = pop.op.shape[1], pop.level_ptr.shape[1]
-        n_in, C = pop.input_pos.shape[1], pop.argmax_pos.shape[1]
-        slots_used = int(pop.n_nodes.sum())
-        stats = {
-            "engine": "pallas",
-            "key": ("netlist_pallas", P, N, Lp1, n_in, C, B + bpad, bt,
-                    bool(interpret)),
-            "cand_real": P, "cand_total": P,
-            # dense (P, N) node tables vs the candidates' real node counts
-            "lanes_used": slots_used, "lanes_total": P * N,
-            "ops_real": _real_ops(pop),
-            "rows_real": B, "rows_total": B + bpad,
-            "tiles": (B + bpad) // bt,
-        }
-        tables = (jnp.asarray(pop.op), jnp.asarray(pop.arg_a),
-                  jnp.asarray(pop.arg_b), jnp.asarray(pop.shift),
-                  jnp.asarray(pop.val.astype(np.int32)),
-                  jnp.asarray(pop.level_ptr), jnp.asarray(pop.input_pos),
-                  jnp.asarray(pop.argmax_pos),
-                  jnp.asarray(xp.astype(np.int32)))
-
-        def _lower():
-            fn = jax.jit(functools.partial(netlist_sim_pallas, block_b=bt,
-                                           interpret=bool(interpret)))
-            return fn.lower(*tables)
-
-        ctx = (PF.dispatch("kernels.netlist_sim.pallas", stats["key"],
-                           lower=_lower, p=P, b=B, tiles=stats["tiles"])
-               if TR.active() else contextlib.nullcontext())
-        with ctx:
-            amx = netlist_sim_pallas(*tables, block_b=bt,
-                                     interpret=bool(interpret))
-            jax.block_until_ready(amx)
-        return np.asarray(amx, np.int64)[:, :B], stats
-
-    raise ValueError(f"unknown engine {engine!r}")
+            amx = _run_levels(*args, vals0, inp_cols, am_cols,
+                              jnp.asarray(tile.astype(dtype)))
+            outs.append(np.asarray(amx[:bt - pad], np.int64))
+    amx = np.concatenate(outs).transpose(1, 0, 2)         # (P_pad, B, C)
+    return amx[:P], stats
 
 
 def simulate_population(pop: PackedPopulation, x: np.ndarray, *,
-                        engine: Optional[str] = None, window: int = 256,
-                        block_b: int = 2048,
-                        interpret: Optional[bool] = None
+                        window: int = 256, block_b: int = 2048
                         ) -> Dict[str, np.ndarray]:
     """Simulate P packed candidates over a batch in one launch.
 
     x: (B, n_in) shared inputs or (P, B, n_in) per-candidate (candidates
     quantizing the ADC lanes at different ``input_bits`` need their own
-    integer features). engine: "levels" | "pallas" | "ref" | None
-    (auto per `repro.configs.backend.default_netlist_engine`).
+    integer features). ``window`` is the wave width, ``block_b`` the
+    largest input tile.
 
     -> {"amx": (P, B, C) int64 comparator operands,
         "argmax": (P, B) int64 class decisions} — bit-exact vs
     `circuit.simulate.simulate` per candidate.
     """
     x = np.asarray(_normalize_x(pop, x))
-    if engine is None:
-        from repro.configs import backend
-        engine = backend.default_netlist_engine()
-    if engine == "ref":
-        return simulate_population_ref(pop, x)
-
     P, B = x.shape[0], x.shape[1]
     MT.counter("netlist_sim.launches").inc()
     MT.counter("netlist_sim.candidates").inc(P)
     if not TR.active():
-        amx, stats = _run_engine(pop, x, engine, window, block_b, interpret)
+        amx, stats = _run_engine(pop, x, window, block_b)
     else:
-        with TR.span("kernels.netlist_sim", engine=engine, p=P, b=B,
+        with TR.span("kernels.netlist_sim", p=P, b=B,
                      slots=int(pop.n_nodes.sum())):
-            amx, stats = _run_engine(pop, x, engine, window, block_b,
-                                     interpret)
+            amx, stats = _run_engine(pop, x, window, block_b)
     _account_padding(stats)
     return {"amx": amx, "argmax": np.argmax(amx, axis=-1).astype(np.int64)}
 
@@ -381,4 +305,4 @@ def population_accuracy(pop: PackedPopulation, x: np.ndarray,
 
 
 __all__ = ["simulate_population", "population_accuracy", "pack_netlist",
-           "pack_population", "simulate_population_ref"]
+           "pack_population"]

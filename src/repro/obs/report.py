@@ -231,7 +231,7 @@ def executables(records: Sequence[Dict]) -> List[Dict]:
 
 def padding_table(records: Sequence[Dict]) -> List[Dict]:
     """Aggregate padding-waste accounting per bucket family: the netlist
-    engines' NOP lanes / repeated candidates / repeated batch rows
+    engine's NOP lanes / repeated candidates / repeated batch rows
     (``netlist_sim.padding``) and the QAT evaluator's population-bucket
     slack (``eval.padding``)."""
     agg: Dict[Tuple[str, str], Dict] = {}
@@ -240,12 +240,12 @@ def padding_table(records: Sequence[Dict]) -> List[Dict]:
             continue
         a = _attrs(r)
         if r["name"] == "netlist_sim.padding":
-            k = ("netlist_sim." + str(a.get("engine")), "lanes")
+            k = ("netlist_sim.levels", "lanes")
             d = agg.setdefault(k, {"launches": 0, "used": 0, "total": 0})
             d["launches"] += 1
             d["used"] += int(a.get("lanes_used", 0))
             d["total"] += int(a.get("lanes_total", 0))
-            k2 = ("netlist_sim." + str(a.get("engine")), "rows")
+            k2 = ("netlist_sim.levels", "rows")
             d2 = agg.setdefault(k2, {"launches": 0, "used": 0, "total": 0})
             d2["launches"] += 1
             d2["used"] += int(a.get("rows_real", 0))

@@ -37,3 +37,23 @@ def rng():
 @pytest.fixture()
 def key():
     return jax.random.PRNGKey(0)
+
+
+@pytest.fixture(scope="session")
+def own_pack():
+    """Runner for evaluations that must score netlists of their own: it
+    empties `batch_eval`'s pack cache, calls ``fn(*a, **kw)`` and checks
+    that no packed node table was read back from the cache (the
+    ``netlist_sim.pack_hits`` counter did not move). Two evaluations of
+    one spec compared through it simulate two independently packed
+    netlists, not one cached table."""
+    from repro.core import batch_eval as BE
+    from repro.obs import metrics as MT
+
+    def run(fn, *a, **kw):
+        BE._PACK_CACHE.clear()
+        hits = MT.counter("netlist_sim.pack_hits").value
+        out = fn(*a, **kw)
+        assert MT.counter("netlist_sim.pack_hits").value == hits
+        return out
+    return run

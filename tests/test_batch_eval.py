@@ -1,5 +1,6 @@
-"""Batched population engine: equivalence with the serial path, vectorized
-CSD pricing, and the persistent evaluation cache."""
+"""Population evaluation: one spec (P = 1) against the same spec in a
+population, vectorized CSD pricing, the bespoke launch, approximated
+candidates in the packed launch, and the persistent evaluation cache."""
 import dataclasses
 
 import numpy as np
@@ -71,18 +72,22 @@ def test_mlp_cost_batch_matches_scalar_per_candidate():
 
 
 # ---------------------------------------------------------------------------
-# batched vs serial evaluation (the tentpole equivalence)
+# population-size invariance: each spec at P = 1 against P = N
 # ---------------------------------------------------------------------------
 
 
 @pytest.fixture(scope="module")
-def serial_and_batched():
-    serial = [MZ.evaluate_spec(CFG, s, epochs=30) for s in SPECS]
-    batched = BE.evaluate_population(CFG, SPECS, epochs=30)
+def serial_and_batched(own_pack):
+    """Each spec alone (`evaluate_spec`, P = 1), and all in one call, each
+    call scoring netlists it packed itself."""
+    serial = [own_pack(MZ.evaluate_spec, CFG, s, epochs=30) for s in SPECS]
+    batched = own_pack(BE.evaluate_population, CFG, SPECS, epochs=30)
     return serial, batched
 
 
 def test_batched_objectives_match_serial(serial_and_batched):
+    """A candidate's objectives do not depend on the population it is
+    finetuned in: P = 1 against P = 6, within 1e-3."""
     serial, batched = serial_and_batched
     for s, b in zip(serial, batched):
         assert abs(s.accuracy - b.accuracy) <= 1e-3, s.spec
@@ -91,18 +96,59 @@ def test_batched_objectives_match_serial(serial_and_batched):
         assert abs(s.power_mw - b.power_mw) <= 1e-3 * max(s.power_mw, 1.0)
 
 
-def test_batched_prices_mixed_input_bits_per_candidate():
+def test_batched_prices_mixed_input_bits_per_candidate(own_pack):
     """Regression: a population mixing input_bits must price each candidate
     at its own input width (prod_width = in_bits + w_bits drives every cost
-    term), matching serial evaluate_spec."""
+    term), matching each candidate evaluated alone (P = 1)."""
     mixed = [ModelMin.uniform(N_LAYERS, bits=4, input_bits=4),
              ModelMin.uniform(N_LAYERS, bits=4, input_bits=8)]
-    serial = [MZ.evaluate_spec(CFG, s, epochs=10) for s in mixed]
-    batched = BE.evaluate_population(CFG, mixed, epochs=10)
+    serial = [own_pack(MZ.evaluate_spec, CFG, s, epochs=10) for s in mixed]
+    batched = own_pack(BE.evaluate_population, CFG, mixed, epochs=10)
     for s, b in zip(serial, batched):
         assert s.area_mm2 == b.area_mm2, s.spec
         assert s.power_mw == b.power_mw, s.spec
         assert abs(s.accuracy - b.accuracy) <= 1e-3, s.spec
+
+
+@pytest.mark.parametrize("spec", [
+    ModelMin.uniform(N_LAYERS, bits=6),
+    ModelMin.uniform(N_LAYERS, bits=4, sparsity=0.3, clusters=4),
+    ModelMin.uniform(N_LAYERS, bits=6, sparsity=0.2, csd_drop=1, lsb=2,
+                     argmax_lsb=2),
+], ids=["exact", "pruned_clustered", "approx"])
+def test_evaluate_spec_is_the_population_of_one(spec, own_pack):
+    """`evaluate_spec` is `evaluate_population` at P = 1: every field of
+    the result equal, approximated specs included, each side scoring a
+    netlist it packed itself."""
+    one = own_pack(MZ.evaluate_spec, CFG, spec, epochs=10)
+    assert one == own_pack(BE.evaluate_population, CFG, [spec],
+                           epochs=10)[0]
+    assert one.delay_levels is not None and one.delay_levels > 0
+
+
+def test_compile_spec_is_the_evaluators_compile():
+    """`circuit.compile_spec` returns the `CompiledMLP` that
+    `minimize.compile_bespoke` gives inside `evaluate_population` for the
+    same spec, and its netlist is that compile's."""
+    from repro import circuit
+    spec = ModelMin.uniform(N_LAYERS, bits=5, sparsity=0.3, clusters=3)
+    seen = []
+    orig = MZ.compile_bespoke
+
+    def spy(params, s, masks, **kw):
+        seen.append(orig(params, s, masks, **kw))
+        return seen[-1]
+
+    mp = pytest.MonkeyPatch()
+    mp.setattr(MZ, "compile_bespoke", spy)
+    try:
+        BE.evaluate_population(CFG, [spec], epochs=10)
+    finally:
+        mp.undo()
+    assert len(seen) == 1
+    net, compiled = circuit.compile_spec(CFG, spec, epochs=10)
+    _assert_same_compiled(compiled, seen[0], every_slot=True)
+    assert len(net) == len(circuit.compile_netlist(seen[0]))
 
 
 def test_batched_preserves_order_and_dedups(serial_and_batched):

@@ -1,8 +1,8 @@
 """Population-batched netlist simulation (`repro.kernels.netlist_sim`):
-packing round-trips, padded mixed-size populations, bit-exactness of every
-engine against `circuit.simulate`, lane-width selection off the verifier's
-per-node bounds, and the batched/serial/fault wiring in
-`core.batch_eval`."""
+packing round-trips, padded mixed-size populations, bit-exactness of the
+`levels` engine against `circuit.simulate` and the NumPy oracle, lane-width
+selection off the verifier's per-node bounds, and the population-size and
+fault wiring in `core.batch_eval`."""
 import jax
 import numpy as np
 import pytest
@@ -10,12 +10,12 @@ import pytest
 from repro import circuit
 from repro.circuit import ir
 from repro.circuit.simulate import Simulator
-from repro.configs import backend
 from repro.configs.printed_mlp import PRINTED_MLPS
 from repro.core import batch_eval as BE
 from repro.core import minimize as MZ
 from repro.core.compression_spec import ModelMin
 from repro.kernels import netlist_sim as NS
+from repro.kernels.netlist_sim import ops as NSO
 from repro.verify.netlist import max_sim_width
 
 from _hypothesis_compat import given, settings, st
@@ -88,13 +88,13 @@ def test_pack_rejects_mixed_arity():
 
 
 # ---------------------------------------------------------------------------
-# engines: bit-exactness
+# the engine: bit-exactness
 # ---------------------------------------------------------------------------
 
 
 def test_padded_population_mixed_sizes_bit_exact():
     """Candidates of very different node counts share one launch; each is
-    bit-exact vs its own serial simulation in every engine."""
+    bit-exact vs its own serial simulation and the NumPy oracle."""
     nets = [_synth_net(d, 5, seed=i) for i, d in enumerate(
         [(7, 3, 3), (7, 28, 3), (7, 14, 14, 3), (7, 5, 3)])]
     sizes = [len(n) for n in nets]
@@ -102,14 +102,11 @@ def test_padded_population_mixed_sizes_bit_exact():
     pop = NS.pack_population(nets)
     x = RNG.integers(0, 2 ** 4, size=(23, 7)).astype(np.int64)
     ref = NS.simulate_population_ref(pop, x)
-    lev = NS.simulate_population(pop, x, engine="levels")
-    pal = NS.simulate_population(pop, x, engine="pallas", interpret=True)
+    lev = NS.simulate_population(pop, x)
     for p, net in enumerate(nets):
         _assert_candidate_matches_serial(ref, p, net, x)
         assert np.array_equal(lev["amx"][p], ref["amx"][p])
-        assert np.array_equal(pal["amx"][p], ref["amx"][p])
         assert np.array_equal(lev["argmax"][p], ref["argmax"][p])
-        assert np.array_equal(pal["argmax"][p], ref["argmax"][p])
 
 
 def test_small_window_many_waves_bit_exact():
@@ -118,8 +115,8 @@ def test_small_window_many_waves_bit_exact():
     nets = [_synth_net((6, 9, 4), 4, seed=s) for s in (0, 1)]
     pop = NS.pack_population(nets)
     x = RNG.integers(0, 2 ** 4, size=(11, 6)).astype(np.int64)
-    wide = NS.simulate_population(pop, x, engine="levels", window=512)
-    narrow = NS.simulate_population(pop, x, engine="levels", window=8)
+    wide = NS.simulate_population(pop, x, window=512)
+    narrow = NS.simulate_population(pop, x, window=8)
     assert np.array_equal(wide["amx"], narrow["amx"])
 
 
@@ -129,8 +126,8 @@ def test_batch_tiling_bit_exact():
     net = _synth_net((5, 6, 3), 4, seed=2)
     pop = NS.pack_population([net])
     x = RNG.integers(0, 2 ** 4, size=(37, 5)).astype(np.int64)
-    whole = NS.simulate_population(pop, x, engine="levels", block_b=2048)
-    tiled = NS.simulate_population(pop, x, engine="levels", block_b=16)
+    whole = NS.simulate_population(pop, x, block_b=2048)
+    tiled = NS.simulate_population(pop, x, block_b=16)
     assert np.array_equal(whole["amx"], tiled["amx"])
 
 
@@ -138,30 +135,28 @@ def test_batch_tiling_bit_exact():
                                      "pendigits"])
 def test_population_engine_bit_exact_on_dataset(dataset):
     """The packed engine is bit-exact against `circuit.simulate.simulate`
-    on real compiled candidates of all four paper datasets."""
+    on real compiled candidates of all four paper datasets, and against the
+    NumPy oracle on the cheap case."""
     cfg = PRINTED_MLPS[dataset]
     n = len(cfg.layer_dims) - 1
-    params0, (xtr, ytr, xte, yte) = MZ.pretrain(cfg, seed=0)
+    _, (_, _, xte, _) = MZ.pretrain(cfg, seed=0)
     specs = [ModelMin.uniform(n, bits=8),
              ModelMin.uniform(n, bits=4, sparsity=0.3)]
     nets, xs = [], []
     for s in specs:
-        masks = MZ.make_masks(params0, s)
-        params = MZ.qat_finetune(params0, s, masks, xtr, ytr, epochs=10)
-        c = MZ.compile_bespoke(params, s, masks)
-        nets.append(circuit.compile_netlist(c))
+        net, c = circuit.compile_spec(cfg, s, epochs=10)
+        nets.append(net)
         xs.append(np.asarray(MZ.quantize_inputs(c, xte[:256]), np.int64))
     pop = NS.pack_population(nets)
-    out = NS.simulate_population(pop, np.stack(xs), engine="levels")
+    out = NS.simulate_population(pop, np.stack(xs))
     for p, net in enumerate(nets):
         serial = circuit.simulate(net, xs[p])    # the acceptance oracle
         assert np.array_equal(out["argmax"][p], serial["argmax"])
         assert np.array_equal(out["amx"][p],
                               serial["logits"].astype(np.int64))
-    if dataset == "seeds":                       # pallas parity, cheap case
-        pal = NS.simulate_population(pop, np.stack(xs), engine="pallas",
-                                     interpret=True)
-        assert np.array_equal(pal["amx"], out["amx"])
+    if dataset == "seeds":                       # oracle parity, cheap case
+        ref = NS.simulate_population_ref(pop, np.stack(xs))
+        assert np.array_equal(ref["amx"], out["amx"])
 
 
 # ---------------------------------------------------------------------------
@@ -181,10 +176,18 @@ def _width32_net():
     return net
 
 
+def _lane(pop, x):
+    """The lane dtype the `levels` engine picks for ``pop``, from the last
+    element of its launch's executable key."""
+    _, stats = NSO._run_engine(pop, np.asarray(x, np.int64)[None]
+                               .repeat(pop.n_candidates, 0), 256, 2048)
+    return stats["key"][-1]
+
+
 def test_width32_net_stays_int32_and_bit_exact():
     """Width-32 words fit int32 exactly; the old whole-net `> 31` check
     promoted them to 64-bit lanes. Bit-exactness holds on the int32 path
-    in the serial simulator and both population engines."""
+    in the serial simulator, the population engine and the oracle."""
     net = _width32_net()
     assert max_sim_width(net) == 32
     assert net.max_width > 31              # the old rule would go int64
@@ -196,33 +199,26 @@ def test_width32_net_stays_int32_and_bit_exact():
     assert np.array_equal(got["logits"].astype(np.int64), expect)
     pop = NS.pack_population([net])
     assert pop.max_width == 32
-    lev = NS.simulate_population(pop, x, engine="levels")
-    pal = NS.simulate_population(pop, x, engine="pallas", interpret=True)
+    assert _lane(pop, x) == "int32"
+    lev = NS.simulate_population(pop, x)
+    ref = NS.simulate_population_ref(pop, x)
     assert np.array_equal(lev["amx"][0], expect)
-    assert np.array_equal(pal["amx"][0], expect)
+    assert np.array_equal(ref["amx"][0], expect)
     assert np.array_equal(lev["argmax"][0], got["argmax"])
 
 
 def test_wide_population_takes_int64_lanes():
-    """Past width 32 the levels engine runs int64 (and the pallas route
-    falls back to it — TPU Pallas has no int64 lanes), still bit-exact."""
+    """Past width 32 the levels engine runs int64 lanes, still bit-exact
+    against the serial simulator and the oracle."""
     net = _synth_net((11, 12, 12, 7), 8, seed=3)
     pop = NS.pack_population([net])
     assert pop.max_width > 32
     x = RNG.integers(0, 2 ** 8, size=(9, 11)).astype(np.int64)
-    lev = NS.simulate_population(pop, x, engine="levels")
-    pal = NS.simulate_population(pop, x, engine="pallas")
+    assert _lane(pop, x) == "int64"
+    lev = NS.simulate_population(pop, x)
     _assert_candidate_matches_serial(lev, 0, net, x)
-    assert np.array_equal(pal["amx"], lev["amx"])
-
-
-def test_engine_env_override(monkeypatch):
-    monkeypatch.setenv("REPRO_NETLIST_ENGINE", "ref")
-    assert backend.default_netlist_engine() == "ref"
-    monkeypatch.setenv("REPRO_NETLIST_ENGINE", "pallas")
-    assert backend.default_netlist_engine() == "pallas"
-    monkeypatch.delenv("REPRO_NETLIST_ENGINE")
-    assert backend.default_netlist_engine() == "levels"
+    assert np.array_equal(NS.simulate_population_ref(pop, x)["amx"],
+                          lev["amx"])
 
 
 # ---------------------------------------------------------------------------
@@ -241,17 +237,19 @@ def test_evalcache_keys_byte_stable():
         base + "|netlist"
 
 
-def test_mixed_input_bits_population_matches_serial():
+def test_mixed_input_bits_population_matches_serial(own_pack):
     """Candidates quantizing the ADC lanes at different input_bits get
     per-candidate integer features inside one packed launch; each equals
-    its serial netlist-exact evaluation."""
+    its netlist-exact evaluation at P = 1 (`evaluate_spec`), each side
+    scoring netlists it packed itself."""
     cfg = PRINTED_MLPS["seeds"]
     n = len(cfg.layer_dims) - 1
     specs = [ModelMin.uniform(n, bits=4, input_bits=4),
              ModelMin.uniform(n, bits=4, input_bits=8)]
-    rs = BE.evaluate_population(cfg, specs, epochs=8)
+    rs = own_pack(BE.evaluate_population, cfg, specs, epochs=8)
     for s, r in zip(specs, rs):
-        assert r.accuracy == MZ.evaluate_spec(cfg, s, epochs=8).accuracy
+        one = own_pack(MZ.evaluate_spec, cfg, s, epochs=8)
+        assert r.accuracy == one.accuracy
 
 
 def test_packed_launch_fault_propagates(monkeypatch, tmp_path):
